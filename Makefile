@@ -2,7 +2,7 @@
 PY ?= python
 
 .PHONY: test test-full lint bench bench-baseline calibrate quickstart deps \
-        serve-smoke fleet-smoke health-smoke kernels-smoke fuzz
+        serve-smoke fleet-smoke health-smoke kernels-smoke chip-smoke fuzz
 
 deps:
 	$(PY) -m pip install -r requirements.txt
@@ -55,12 +55,15 @@ health-smoke:       # scripted comm faults: guards + monitor + quarantine
 	    --bucket-edges 8
 
 kernels-smoke:      # Pallas kernel suites incl. the chunk-pipelined fused
-	            # collectives (interpret-mode tests skip cleanly on JAX
-	            # builds without pltpu.InterpretParams; on TPU they run
-	            # against the hardware)
+	            # collectives: TPU interpret mode on CPU, plus Mosaic
+	            # compiles of the fused kernels for a described v5e 2x2
 	XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	    PYTHONPATH=src $(PY) -m pytest -q -rs tests/test_kernels.py \
-	    tests/test_pk_comm.py tests/test_fused_chunks.py
+	    tests/test_pk_comm.py tests/test_fused_chunks.py \
+	    tests/test_tpu_compile.py
+
+chip-smoke:         # tinyllama-1.1b at full width on one TPU (fails off-TPU)
+	$(PY) chip_smoke.py
 
 fuzz:               # slow randomized/property tests (uses hypothesis if installed)
 	PYTHONPATH=src $(PY) -m pytest -q -m slow tests/test_property.py

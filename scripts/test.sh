@@ -50,9 +50,9 @@ fi
 
 # Stage 5 (non-blocking, opt-in): the Pallas kernel smoke (`make
 # kernels-smoke`): the matmul/attention kernel suite plus the ring and
-# chunk-pipelined fused collective kernels — redundant with stage 1 on
-# this container (the interpret-gated tests skip), so it is opt-in for
-# machines where the kernels actually execute. Enable with REPRO_KERNELS=1.
+# chunk-pipelined fused collective kernels. Stage 1 already runs them (TPU
+# interpret mode on CPU, Mosaic compiles for a described v5e), so this is
+# an opt-in re-run of just those files. Enable with REPRO_KERNELS=1.
 if [ "${REPRO_KERNELS:-0}" = "1" ]; then
     if ! make kernels-smoke; then
         echo "WARNING: kernels-smoke stage failed (non-blocking; run" \

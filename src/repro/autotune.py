@@ -127,7 +127,7 @@ def int8_island_sweeps(islands):
 def cmd_calibrate(args) -> int:
     from repro.core import autotune, costmodel
 
-    hw = getattr(costmodel, args.hw.upper())
+    hw = costmodel.spec_by_name(args.hw)
     dtypes = {"bf16": (2,), "int8": (1,), "both": (2, 1)}[args.dtype]
     islands = list(_island_sweeps(args)) if args.per_island else []
     if 1 in dtypes and islands:
@@ -169,7 +169,7 @@ def cmd_diff(args) -> int:
     from repro.core import autotune, costmodel
 
     a = autotune.CalibrationTable.load(args.a)
-    base = getattr(costmodel, a.fingerprint.hw.upper(), costmodel.TPU_V5E)
+    base = costmodel.spec_by_name(a.fingerprint.hw)
     if args.b is None:
         # one-sided: measured vs the analytic spec it corrects
         print(f"{args.a} vs analytic {base.name}:")

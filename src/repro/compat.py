@@ -1,114 +1,58 @@
-"""JAX version compatibility layer.
-
-The codebase targets the current JAX API (``jax.shard_map``,
-``jax.sharding.AxisType``, ``pltpu.CompilerParams`` /
-``pltpu.InterpretParams``); older releases (e.g. 0.4.x) spell these
-differently or lack them entirely. Every use of a version-sensitive symbol
-goes through this module so the rest of the tree stays on the modern
-spelling.
+"""The one place the tree touches JAX's mesh, shard_map and Pallas-TPU
+spellings, and the process-wide compile cache.
 
 Exports
 -------
 shard_map(f, *, mesh, in_specs, out_specs, check_vma)
-    ``jax.shard_map`` when present, else ``jax.experimental.shard_map``
-    with ``check_vma`` mapped onto the old ``check_rep`` flag.
+    ``jax.shard_map``. Only ``core/template.py`` (and the calibration
+    harness) may call it — tests/test_template.py guards that.
 make_mesh(shape, axes)
-    ``jax.make_mesh`` with explicit ``AxisType.Auto`` axis types when the
-    running JAX supports them, and without the kwarg when it does not.
-CompilerParams / interpret_params() / ANY / hbm_scratch()
-    Pallas-TPU naming shims.
-HAS_TPU_INTERPRET
-    True iff this JAX ships the TPU interpret mode (per-device semaphore +
-    remote-DMA emulation on CPU). The Pallas communication kernels need it
-    to run anywhere but a real TPU.
-default_interpret()
-    The interpret-mode default every kernel wrapper shares: interpret off
-    on a real TPU, on elsewhere.
+    ``jax.make_mesh`` with explicit ``AxisType.Auto`` axis types.
+axis_size / CompilerParams / ANY / interpret_params()
+    ``lax.axis_size`` and the Pallas-TPU names the kernels use.
+default_interpret() / kernel_interpret(interpret)
+    The interpret-mode default every kernel wrapper shares: compiled on a
+    TPU, TPU interpret mode (semaphore + remote-DMA emulation) elsewhere.
+enable_compile_cache()
+    JAX's persistent compilation cache at ``$JAX_COMPILATION_CACHE_DIR``,
+    or at ``<repo>/.jax_cache`` when that variable is unset.
 """
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
-from jax.experimental import pallas as pl  # noqa: F401  (re-export surface)
+from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
-# --- mesh construction ------------------------------------------------------
+axis_size = lax.axis_size
+CompilerParams = pltpu.CompilerParams
+ANY = pltpu.MemorySpace.ANY
 
-try:
-    _AXIS_TYPE_AUTO = jax.sharding.AxisType.Auto
-except AttributeError:          # jax < 0.5: meshes have no axis types
-    _AXIS_TYPE_AUTO = None
-
-HAS_AXIS_TYPES = _AXIS_TYPE_AUTO is not None
+#: cache location used when ``JAX_COMPILATION_CACHE_DIR`` is unset; fixed
+#: (no temporary name), so a second run in the same checkout finds the
+#: first run's executables
+REPO_COMPILE_CACHE = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` across JAX versions (axis_types feature-detected)."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if HAS_AXIS_TYPES:
-        kwargs["axis_types"] = (_AXIS_TYPE_AUTO,) * len(tuple(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(tuple(axes)), **kwargs)
 
 
-# --- named-axis helpers -----------------------------------------------------
-
-from jax import lax as _lax
-
-if hasattr(_lax, "axis_size"):
-    axis_size = _lax.axis_size
-else:                           # jax < 0.6: psum of a literal folds statically
-    def axis_size(axis_name):
-        return _lax.psum(1, axis_name)
-
-
-# --- shard_map --------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-else:                           # jax < 0.6: experimental, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-
-
-# --- Pallas TPU naming ------------------------------------------------------
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
-_InterpretParams = getattr(pltpu, "InterpretParams", None) \
-    or getattr(pltpu, "TPUInterpretParams", None)
-
-#: True iff pltpu ships the TPU interpret mode (semaphore/remote-DMA
-#: emulation). Without it the communication kernels only run on real TPUs.
-HAS_TPU_INTERPRET = _InterpretParams is not None
-
-if hasattr(pltpu, "MemorySpace"):
-    ANY = pltpu.MemorySpace.ANY
-    _HBM = getattr(pltpu.MemorySpace, "HBM", pltpu.MemorySpace.ANY)
-else:                           # jax < 0.6: TPUMemorySpace enum
-    ANY = pltpu.TPUMemorySpace.ANY
-    _HBM = getattr(pltpu.TPUMemorySpace, "HBM", pltpu.TPUMemorySpace.ANY)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def interpret_params(**kwargs):
-    """TPU InterpretParams, or a clear error on JAX builds without it."""
-    if _InterpretParams is None:
-        raise NotImplementedError(
-            "This JAX build has no pltpu.InterpretParams — the Pallas "
-            "communication kernels can only run on a real TPU backend.")
-    return _InterpretParams(**kwargs)
-
-
-def hbm_scratch(shape, dtype):
-    """HBM-resident kernel scratch (landing buffers for ring kernels)."""
-    return _HBM(tuple(shape), dtype)
+    """TPU interpret mode: per-device semaphores and remote DMAs on CPU."""
+    return pltpu.InterpretParams(**kwargs)
 
 
 def default_interpret() -> bool:
@@ -116,6 +60,24 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tpu_kernels_supported() -> bool:
-    """Can the Pallas communication kernels execute here at all?"""
-    return jax.default_backend() == "tpu" or HAS_TPU_INTERPRET
+def kernel_interpret(interpret: bool | None):
+    """The ``pallas_call(interpret=...)`` value of a communication kernel.
+    ``None`` resolves through ``default_interpret()``, so a TPU always
+    compiles; interpreting means the TPU interpret mode, the one that
+    emulates remote DMAs."""
+    if interpret is None:
+        interpret = default_interpret()
+    return interpret_params() if interpret else False
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads the
+    variable itself) and nothing else is set. Otherwise the cache lives at
+    the fixed ``<repo>/.jax_cache``."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_COMPILE_CACHE))
+    return str(REPO_COMPILE_CACHE)

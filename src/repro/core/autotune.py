@@ -578,7 +578,7 @@ def staleness(table: CalibrationTable, *,
         return msgs
     from repro.core import costmodel as cm
 
-    hw = getattr(cm, table.fingerprint.hw.upper(), cm.TPU_V5E)
+    hw = cm.spec_by_name(table.fingerprint.hw)
     probes = {"kernel_launch_s": _measure_launch(reps),
               "gemm_efficiency": _measure_gemm_efficiency(hw, reps)}
     for key, now in probes.items():

@@ -37,7 +37,7 @@ Ops (uniform signature: operands, then ``backend=None`` plus op kwargs):
               ceil/floor
 ``chunked`` — payload split so downstream compute overlaps later chunks
 ``fused``   — single Pallas kernel with intra-kernel RDMA overlap (LCSC
-              template; needs a TPU backend or TPU interpret mode). Each
+              template; compiled on a TPU, TPU interpret mode elsewhere). Each
               ring hop is itself chunk-pipelined: per-chunk one-way DMAs
               issued ahead of the chunk GEMM, same ``ChunkSchedule``
               resolution as the jax-level rings but priced with
@@ -75,7 +75,8 @@ Dispatch rules (``backend=None``): GEMM×collective ops go through
 ``schedule.choose_gemm_collective`` — bulk when the GEMM is too small to
 cover the ring's sync overhead, ``ring_bidir`` when the axis is even and
 bidirectional rings are allowed, ``ring`` otherwise, ``fused`` on a real TPU
-when the operands fit VMEM. ``all_to_all`` picks its chunk count from
+when the kernel compiles at the shape and its scratch fits VMEM
+(``CommContext.fused_fits``). ``all_to_all`` picks its chunk count from
 ``schedule.choose_a2a_chunks``.
 
 Analytic vs measured costs (``policy=``)
@@ -149,6 +150,7 @@ _CANONICAL_KERNELS = (
     "ag_matmul_fused",
     "matmul_rs_fused",
     "lcsc_ring_all_gather",
+    "matmul_ar_fused",
 )
 
 
@@ -192,8 +194,7 @@ for _name in _CANONICAL_KERNELS:
 
 
 # ---------------------------------------------------------------------------
-# The op/backend registry. "fused" backends additionally require
-# compat.tpu_kernels_supported() at run time.
+# The op/backend registry.
 # ---------------------------------------------------------------------------
 
 OP_BACKENDS: dict[str, tuple[str, ...]] = {
@@ -206,8 +207,6 @@ OP_BACKENDS: dict[str, tuple[str, ...]] = {
     "reduce_scatter": ("bulk", "fused"),
     "ring_shift": ("bulk", "fused"),
 }
-
-_FUSED = ("fused",)
 
 _ALL_BACKENDS = {b for bs in OP_BACKENDS.values() for b in bs}
 
@@ -290,17 +289,15 @@ class CommContext:
         return compat.axis_size(self.axis_name)
 
     def available_backends(self, op: str) -> tuple[str, ...]:
-        """Backends of `op` that can actually execute in this process.
+        """Backends of `op` (fused ones run compiled on a TPU and in TPU
+        interpret mode elsewhere).
 
         Example::
 
             >>> CommContext(axis_name="x").available_backends("psum")
             ('bulk', 'ring')
         """
-        names = OP_BACKENDS[op]
-        if compat.tpu_kernels_supported():
-            return names
-        return tuple(b for b in names if b not in _FUSED)
+        return OP_BACKENDS[op]
 
     def active_calibration(self):
         """The ``CalibrationTable`` this context's policy dispatches from,
@@ -343,10 +340,6 @@ class CommContext:
             raise ValueError(
                 f"op {op!r} has no backend {be!r}; "
                 f"available: {OP_BACKENDS[op]}")
-        if be in _FUSED and not compat.tpu_kernels_supported():
-            raise NotImplementedError(
-                f"backend 'fused' for {op!r} needs a TPU backend or a JAX "
-                "with pltpu.InterpretParams (TPU interpret mode)")
         return be
 
     def _shape_guard(self, op: str, be: str, override: str | None,
@@ -365,13 +358,19 @@ class CommContext:
                 f"(axis {self.axis_name!r} has size {self.axis_size})")
         return fallback
 
-    def _prefer_fused(self, *operands, out_bytes: int) -> bool:
-        """Auto-pick the fused Pallas kernel only on a real TPU and only when
-        the whole-operand VMEM residency the kernels assume actually fits."""
+    def fused_fits(self, op: str, m: int, n: int, k: int, *,
+                   dtype_bytes: int = 2) -> bool:
+        """May the policy pick the fused Pallas kernel for GEMM×collective
+        ``op`` at dispatch coordinates (m, n, k)? Only compiled on a real
+        TPU, at a shape the chip's compiler accepts, with the kernel's whole
+        VMEM scratch (f32 accumulators included) inside ``hw.vmem_bytes``.
+        Dispatch and ``Island.plan()`` both ask this, so they agree."""
         if jax.default_backend() != "tpu" or self._interpret_mode():
             return False
-        footprint = sum(x.size * x.dtype.itemsize for x in operands) + out_bytes
-        return footprint <= self.hw.vmem_bytes
+        from repro.kernels import collective_matmul
+        return collective_matmul.fused_fits(
+            op, m, n, k, self.axis_size, dtype_bytes=dtype_bytes,
+            budget=self.hw.vmem_bytes)
 
     def gemm_policy(self, m: int, n: int, k: int, *, kind: str,
                     dtype_bytes: int = 2, hw: cm.HardwareSpec | None = None,
@@ -613,8 +612,9 @@ class CommContext:
             return self.auto_gemm_backend(
                 "all_gather_matmul", m_loc * n_dev, n_out, k,
                 dtype_bytes=x.dtype.itemsize,
-                fused_ok=self._prefer_fused(
-                    x, w, out_bytes=m_loc * n_dev * n_out * 4),
+                fused_ok=self.fused_fits("all_gather_matmul", m_loc * n_dev,
+                                         n_out, k,
+                                         dtype_bytes=x.dtype.itemsize),
                 bidir_ok=(m_loc >= 2), wire=fmt)
 
         be = self._resolve("all_gather_matmul", backend, auto)
@@ -679,8 +679,9 @@ class CommContext:
             return self.auto_gemm_backend(
                 "matmul_reduce_scatter", m, n_out, k_loc,
                 dtype_bytes=x.dtype.itemsize,
-                fused_ok=self._prefer_fused(
-                    x, w, out_bytes=(m // n_dev) * n_out * 4), wire=fmt)
+                fused_ok=self.fused_fits("matmul_reduce_scatter", m, n_out, k_loc,
+                                         dtype_bytes=x.dtype.itemsize),
+                wire=fmt)
 
         be = self._resolve("matmul_reduce_scatter", backend, auto)
         if be != "bulk":
@@ -738,8 +739,9 @@ class CommContext:
             return self.auto_gemm_backend(
                 "matmul_all_reduce", m, n_out, k_loc,
                 dtype_bytes=x.dtype.itemsize,
-                fused_ok=self._prefer_fused(
-                    x, w, out_bytes=(m // n_dev) * n_out * 4), wire=fmt)
+                fused_ok=self.fused_fits("matmul_all_reduce", m, n_out, k_loc,
+                                         dtype_bytes=x.dtype.itemsize),
+                wire=fmt)
 
         be = self._resolve("matmul_all_reduce", backend, auto)
         if be != "bulk":

@@ -74,6 +74,21 @@ H100_SXM = HardwareSpec(
     vmem_bytes=227 * 2**10,
 )
 
+SPECS = {s.name: s for s in (TPU_V5E, H100_SXM)}
+
+#: ``jax.devices()[0].device_kind`` -> the spec of that chip. A kind that is
+#: not here has no spec: callers refuse it rather than assume v5e peaks.
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def spec_by_name(name: str) -> HardwareSpec:
+    """The ``HardwareSpec`` named ``name`` (case-insensitive)."""
+    try:
+        return SPECS[name.lower()]
+    except KeyError:
+        raise KeyError(f"no HardwareSpec named {name!r}; known: "
+                       f"{sorted(SPECS)}") from None
+
 
 @dataclasses.dataclass(frozen=True)
 class KernelCost:

@@ -40,18 +40,28 @@ GEMM_CHUNK_DIM = {"all_gather": "m", "reduce_scatter": "m", "all_reduce": "m"}
 CHUNK_CANDIDATES = (1, 2, 4, 8)
 
 
-def fit_chunks(extent: int, n_chunks: int) -> int:
-    """Largest divisor of ``extent`` that is <= ``n_chunks`` (always >= 1).
+def row_tile(dtype_bytes: int) -> int:
+    """Rows of one TPU (sublane x lane) tile: 8 for f32, 16 for bf16, 32
+    for int8. A Pallas row slice that is not the whole array must start and
+    end on a multiple of it."""
+    return 8 * 4 // dtype_bytes
+
+
+def fit_chunks(extent: int, n_chunks: int, align: int = 1) -> int:
+    """Largest divisor of ``extent`` that is <= ``n_chunks`` and leaves
+    chunks of a multiple of ``align`` rows (always >= 1: one chunk is the
+    whole extent).
 
     The non-divisible fallback for every chunked schedule: a chunk count that
     does not divide the chunked sub-shape degrades to the nearest one that
     does instead of raising — chunking is an optimization, never a new shape
-    constraint.
+    constraint. ``align`` is the Pallas kernels' row tiling (``row_tile``):
+    the chip's compiler refuses a row slice that is not tile-aligned.
     """
     if extent <= 0:
         return 1
     c = max(1, min(n_chunks, extent))
-    while extent % c:
+    while c > 1 and (extent % c or (extent // c) % align):
         c -= 1
     return c
 

@@ -517,16 +517,12 @@ class Island:
             # context pins degrade via _shape_guard); the bidirectional AG
             # ring additionally needs >= 2 local rows to split across the
             # two directions (odd shards split unevenly); the fused Pallas
-            # kernel is auto-picked only on a real TPU with the
-            # (approximate, coordinate-derived) operand footprint in VMEM.
+            # kernel is auto-picked by the same ``fused_fits`` test dispatch
+            # runs (real TPU, compilable shape, scratch inside VMEM).
             ring_ok = c.op == "all_gather_matmul" or c.m % n_dev == 0
             m_loc = c.m // n_dev if c.m % n_dev == 0 else c.m
-            fused_ok = False
-            if jax.default_backend() == "tpu" and not ctx._interpret_mode():
-                x_rows = m_loc if c.op == "all_gather_matmul" else c.m
-                footprint = ((x_rows + c.n) * c.k * c.dtype_bytes
-                             + max(m_loc, 1) * c.n * 4)
-                fused_ok = footprint <= ctx.hw.vmem_bytes
+            fused_ok = ctx.fused_fits(c.op, c.m, c.n, c.k,
+                                      dtype_bytes=c.dtype_bytes)
             if c.backend is not None:
                 # call-site pin: the body passes backend= explicitly, which
                 # the runtime enforces — a shape violation RAISES there

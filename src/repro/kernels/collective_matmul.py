@@ -40,10 +40,61 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import compat
 from repro.core.comms import collective_id
-from repro.core.schedule import fit_chunks
+from repro.core.schedule import fit_chunks, row_tile
 
 from repro.kernels.pk_comm import (pk_neighbor_barrier, pk_signal,
                                    pk_store_async, pk_store_chunked, pk_wait)
+
+
+# ---------------------------------------------------------------------------
+# What the TPU compiler accepts: row tiling and VMEM footprint. Dispatch
+# (CommContext.fused_fits) and Island.plan() both read ``fused_fits``, so
+# `fused` is only ever chosen at a shape the kernel compiles at.
+# ---------------------------------------------------------------------------
+
+#: Mosaic's default scoped-VMEM limit on v5e; kernels that need more ask
+#: for it explicitly through ``vmem_limit_bytes``
+DEFAULT_VMEM_LIMIT = 16 * 2**20
+#: what Mosaic keeps beside the declared scratch (dot results, spills)
+_VMEM_HEADROOM = 8 * 2**20
+
+
+def _rs_align(dtype_bytes: int) -> int:
+    # the RS/AR chunks slice f32 accumulators and x.dtype blocks alike
+    return max(row_tile(4), row_tile(dtype_bytes))
+
+
+def fused_vmem_bytes(op: str, rows: int, n: int, k: int,
+                     dtype_bytes: int = 2) -> int:
+    """VMEM scratch of the fused kernel for GEMM×collective ``op`` holding
+    ``rows`` rows per device (the x shard for AG, the output block for
+    RS/AR), local n (AG) or local k (RS/AR)."""
+    if op == "all_gather_matmul":
+        # double-buffered x shards + resident w + one output shard
+        return (2 * rows * k + k * n + rows * n) * dtype_bytes
+    # acc / partial / landing-copy f32 blocks + x block + resident w
+    return 3 * rows * n * 4 + (rows * k + k * n) * dtype_bytes
+
+
+def fused_fits(op: str, m: int, n: int, k: int, n_dev: int, *,
+               dtype_bytes: int, budget: float) -> bool:
+    """Does the chip's compiler accept the fused kernel for ``op`` at the
+    dispatch coordinates (m, n, k) over ``n_dev`` devices, with its VMEM
+    scratch inside ``budget``? RS/AR slice the operand in row blocks, which
+    must be tile-aligned; AG moves whole shards."""
+    if m % n_dev:
+        return False
+    rows = m // n_dev
+    if op != "all_gather_matmul" and rows % _rs_align(dtype_bytes):
+        return False
+    return fused_vmem_bytes(op, rows, n, k, dtype_bytes) <= budget
+
+
+def vmem_limit(scratch_bytes: int) -> int:
+    """``vmem_limit_bytes`` for a kernel holding ``scratch_bytes`` of
+    declared scratch: the default limit unless the scratch plus Mosaic's
+    own needs exceed it."""
+    return max(DEFAULT_VMEM_LIMIT, scratch_bytes + _VMEM_HEADROOM)
 
 
 # ---------------------------------------------------------------------------
@@ -114,7 +165,7 @@ def _ag_mm_kernel(x_ref, w_ref, out_ref, buf, w_v, y_v, send_sem, recv_sem,
 
 
 def ag_matmul_fused(x, w, axis_name: str, *, n_chunks: int = 1,
-                    interpret=True):
+                    interpret: bool | None = None):
     """x: (m_loc, k) row shard; w: (k, n) local weight. Returns
     (n_dev*m_loc, n) — all-gather fused into the GEMM. Call inside shard_map.
     ``n_chunks`` splits each hop into row sub-chunks (largest-divisor
@@ -124,8 +175,10 @@ def ag_matmul_fused(x, w, axis_name: str, *, n_chunks: int = 1,
     n_dev = compat.axis_size(axis_name)
     m_loc, k = x.shape
     n = w.shape[1]
-    n_chunks = fit_chunks(m_loc, n_chunks)
+    n_chunks = fit_chunks(m_loc, n_chunks, align=row_tile(x.dtype.itemsize))
     m_chunk = m_loc // n_chunks
+    vmem = fused_vmem_bytes("all_gather_matmul", m_loc, n, k,
+                            x.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_ag_mm_kernel, axis_name=axis_name, n_dev=n_dev,
                           n_chunks=n_chunks, m_chunk=m_chunk),
@@ -140,8 +193,10 @@ def ag_matmul_fused(x, w, axis_name: str, *, n_chunks: int = 1,
                         pltpu.SemaphoreType.DMA((n_dev - 1, n_chunks)),
                         pltpu.SemaphoreType.REGULAR((2,)),
                         pltpu.SemaphoreType.DMA],
-        compiler_params=compat.CompilerParams(collective_id=collective_id("ag_matmul_fused")),
-        interpret=compat.interpret_params() if interpret else False,
+        compiler_params=compat.CompilerParams(
+            collective_id=collective_id("ag_matmul_fused"),
+            vmem_limit_bytes=vmem_limit(vmem)),
+        interpret=compat.kernel_interpret(interpret),
     )(x, w)
 
 
@@ -229,7 +284,7 @@ def _mm_rs_kernel(x_ref, w_ref, out_ref, landing, acc_v, p_v, l_v, x_v, w_v,
 
 
 def matmul_rs_fused(x, w, axis_name: str, *, n_chunks: int = 1,
-                    interpret=True):
+                    interpret: bool | None = None):
     """x: (m, k_loc); w: (k_loc, n) (K sharded over the axis). Returns the
     reduce-scattered (m/n_dev, n) fp32 shard. Call inside shard_map.
     ``n_chunks`` splits each hop's accumulator payload into row sub-chunks
@@ -239,17 +294,21 @@ def matmul_rs_fused(x, w, axis_name: str, *, n_chunks: int = 1,
     n = w.shape[1]
     assert m % n_dev == 0
     m_blk = m // n_dev
-    n_chunks = fit_chunks(m_blk, n_chunks)
+    n_chunks = fit_chunks(m_blk, n_chunks, align=_rs_align(x.dtype.itemsize))
     m_chunk = m_blk // n_chunks
+    vmem = fused_vmem_bytes("matmul_reduce_scatter", m_blk, n, k_loc, x.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_mm_rs_kernel, axis_name=axis_name, n_dev=n_dev,
                           m_blk=m_blk, n_chunks=n_chunks, m_chunk=m_chunk),
         in_specs=[pl.BlockSpec(memory_space=compat.ANY),
                   pl.BlockSpec(memory_space=compat.ANY)],
-        out_specs=pl.BlockSpec(memory_space=compat.ANY),
-        out_shape=jax.ShapeDtypeStruct((m_blk, n), jnp.float32),
-        scratch_shapes=[compat.hbm_scratch((2, m_blk, n), jnp.float32),
-                        pltpu.VMEM((m_blk, n), jnp.float32),
+        # the landing double buffer is a second (discarded) output: remote
+        # DMAs need an HBM destination, and Mosaic scratch is VMEM/SMEM only
+        out_specs=(pl.BlockSpec(memory_space=compat.ANY),
+                   pl.BlockSpec(memory_space=compat.ANY)),
+        out_shape=(jax.ShapeDtypeStruct((m_blk, n), jnp.float32),
+                   jax.ShapeDtypeStruct((2, m_blk, n), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((m_blk, n), jnp.float32),
                         pltpu.VMEM((m_blk, n), jnp.float32),
                         pltpu.VMEM((m_blk, n), jnp.float32),
                         pltpu.VMEM((m_blk, k_loc), x.dtype),
@@ -258,9 +317,11 @@ def matmul_rs_fused(x, w, axis_name: str, *, n_chunks: int = 1,
                         pltpu.SemaphoreType.DMA((n_dev - 1, n_chunks)),
                         pltpu.SemaphoreType.REGULAR((2,)),
                         pltpu.SemaphoreType.DMA],
-        compiler_params=compat.CompilerParams(collective_id=collective_id("matmul_rs_fused")),
-        interpret=compat.interpret_params() if interpret else False,
-    )(x, w)
+        compiler_params=compat.CompilerParams(
+            collective_id=collective_id("matmul_rs_fused"),
+            vmem_limit_bytes=vmem_limit(vmem)),
+        interpret=compat.kernel_interpret(interpret),
+    )(x, w)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -304,7 +365,7 @@ def _mm_ar_kernel(x_ref, w_ref, out_ref, landing, acc_v, p_v, l_v, x_v, w_v,
 
 
 def matmul_ar_fused(x, w, axis_name: str, *, n_chunks: int = 1,
-                    interpret=True):
+                    interpret: bool | None = None):
     """x: (m, k_loc); w: (k_loc, n) (K sharded over the axis). Returns the
     all-reduced (n_dev, m/n_dev, n) fp32 blocks (reshape to (m, n) outside).
     Call inside shard_map. One kernel end to end: the GEMM×RS ring followed
@@ -316,17 +377,21 @@ def matmul_ar_fused(x, w, axis_name: str, *, n_chunks: int = 1,
     n = w.shape[1]
     assert m % n_dev == 0
     m_blk = m // n_dev
-    n_chunks = fit_chunks(m_blk, n_chunks)
+    n_chunks = fit_chunks(m_blk, n_chunks, align=_rs_align(x.dtype.itemsize))
     m_chunk = m_blk // n_chunks
+    vmem = fused_vmem_bytes("matmul_all_reduce", m_blk, n, k_loc, x.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_mm_ar_kernel, axis_name=axis_name, n_dev=n_dev,
                           m_blk=m_blk, n_chunks=n_chunks, m_chunk=m_chunk),
         in_specs=[pl.BlockSpec(memory_space=compat.ANY),
                   pl.BlockSpec(memory_space=compat.ANY)],
-        out_specs=pl.BlockSpec(memory_space=compat.ANY),
-        out_shape=jax.ShapeDtypeStruct((n_dev, m_blk, n), jnp.float32),
-        scratch_shapes=[compat.hbm_scratch((2, m_blk, n), jnp.float32),
-                        pltpu.VMEM((m_blk, n), jnp.float32),
+        # the landing double buffer is a second (discarded) output: remote
+        # DMAs need an HBM destination, and Mosaic scratch is VMEM/SMEM only
+        out_specs=(pl.BlockSpec(memory_space=compat.ANY),
+                   pl.BlockSpec(memory_space=compat.ANY)),
+        out_shape=(jax.ShapeDtypeStruct((n_dev, m_blk, n), jnp.float32),
+                   jax.ShapeDtypeStruct((2, m_blk, n), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((m_blk, n), jnp.float32),
                         pltpu.VMEM((m_blk, n), jnp.float32),
                         pltpu.VMEM((m_blk, n), jnp.float32),
                         pltpu.VMEM((m_blk, k_loc), x.dtype),
@@ -337,6 +402,8 @@ def matmul_ar_fused(x, w, axis_name: str, *, n_chunks: int = 1,
                         pltpu.SemaphoreType.DMA((n_dev - 1, n_chunks)),
                         pltpu.SemaphoreType.REGULAR((2,)),
                         pltpu.SemaphoreType.DMA],
-        compiler_params=compat.CompilerParams(collective_id=collective_id("matmul_ar_fused")),
-        interpret=compat.interpret_params() if interpret else False,
-    )(x, w)
+        compiler_params=compat.CompilerParams(
+            collective_id=collective_id("matmul_ar_fused"),
+            vmem_limit_bytes=vmem_limit(vmem)),
+        interpret=compat.kernel_interpret(interpret),
+    )(x, w)[0]

@@ -107,7 +107,8 @@ def lcsc_kernel(*, n_steps_from_ndev: Callable[[int], int],
 # equivalent to kernels/pk_comm.ring_all_gather.
 # ---------------------------------------------------------------------------
 
-def lcsc_ring_all_gather(x, axis_name: str, *, interpret=True):
+def lcsc_ring_all_gather(x, axis_name: str, *,
+                         interpret: bool | None = None):
     n_dev = compat.axis_size(axis_name)
 
     def prologue(c):             # stage the local shard into my PGL slot
@@ -133,6 +134,7 @@ def lcsc_ring_all_gather(x, axis_name: str, *, interpret=True):
         scratch_shapes=[pltpu.SemaphoreType.DMA((n_dev - 1,)),
                         pltpu.SemaphoreType.DMA((n_dev - 1,)),
                         pltpu.SemaphoreType.DMA],
-        compiler_params=compat.CompilerParams(collective_id=collective_id("lcsc_ring_all_gather")),
-        interpret=compat.interpret_params() if interpret else False,
+        compiler_params=compat.CompilerParams(
+            collective_id=collective_id("lcsc_ring_all_gather")),
+        interpret=compat.kernel_interpret(interpret),
     )(x)

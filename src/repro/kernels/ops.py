@@ -21,10 +21,6 @@ from repro.kernels.pk_comm import (p2p_ring_shift, ring_all_gather,
                                    ring_reduce_scatter)
 
 
-def _on_tpu() -> bool:
-    return not compat.default_interpret()
-
-
 def _pad_to(x, mult: int, axis: int):
     s = x.shape[axis]
     pad = (-s) % mult
@@ -37,7 +33,7 @@ def _pad_to(x, mult: int, axis: int):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def matmul(x, w, *, bm=128, bn=128, bk=128, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = compat.default_interpret() if interpret is None else interpret
     x, m0 = _pad_to(x, bm, 0)
     x, _ = _pad_to(x, bk, 1)
     w, _ = _pad_to(w, bk, 0)
@@ -51,7 +47,7 @@ def matmul(x, w, *, bm=128, bn=128, bk=128, interpret=None):
 def flash_attention(q, k, v, *, causal=True, window=None, blq=128, blk=128,
                     interpret=None):
     """q: (B, Hq, S, D); k, v: (B, Hkv, S, D). GQA: kv heads repeated."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = compat.default_interpret() if interpret is None else interpret
     hq, hkv = q.shape[1], k.shape[1]
     if hq != hkv:
         k = jnp.repeat(k, hq // hkv, axis=1)
@@ -74,7 +70,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, blq=128, blk=128,
 
 @functools.partial(jax.jit, static_argnames=("bc", "bf", "bk", "interpret"))
 def grouped_matmul(x, w, *, bc=128, bf=128, bk=128, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = compat.default_interpret() if interpret is None else interpret
     x, c0 = _pad_to(x, bc, 1)
     x, _ = _pad_to(x, bk, 2)
     w, _ = _pad_to(w, bk, 1)
@@ -85,7 +81,7 @@ def grouped_matmul(x, w, *, bc=128, bf=128, bk=128, interpret=None):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mamba_scan(dt, b_ssm, c_ssm, x, a, h0, *, chunk=128, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = compat.default_interpret() if interpret is None else interpret
     return _mscan(dt, b_ssm, c_ssm, x, a, h0, chunk=chunk,
                   interpret=interpret)
 
@@ -96,15 +92,14 @@ def mamba_scan(dt, b_ssm, c_ssm, x, a, h0, *, chunk=128, interpret=None):
 # resolved by ``CommContext.gemm_chunk_schedule`` (explicit > RunConfig >
 # measured table > analytic fused cost term) lands here and is fitted to the
 # payload rows by the kernel wrappers (``fit_chunks`` — never a constraint).
+# ``interpret=None`` resolves in the kernels (``compat.kernel_interpret``).
 
 def pk_all_gather(x, axis_name, *, n_chunks=1, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return ring_all_gather(x, axis_name, n_chunks=n_chunks,
                            interpret=interpret)
 
 
 def pk_reduce_scatter(x, axis_name, *, n_chunks=1, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return ring_reduce_scatter(x, axis_name, n_chunks=n_chunks,
                                interpret=interpret)
 
@@ -126,19 +121,16 @@ def pk_all_reduce(x, axis_name, *, n_chunks=1, interpret=None):
 
 
 def pk_ring_shift(x, axis_name, *, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return p2p_ring_shift(x, axis_name, interpret=interpret)
 
 
 def pk_ag_matmul(x, w, axis_name, *, n_chunks=1, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     out = ag_matmul_fused(x, w, axis_name, n_chunks=n_chunks,
                           interpret=interpret)
     return out.reshape(-1, w.shape[1])
 
 
 def pk_matmul_rs(x, w, axis_name, *, n_chunks=1, interpret=None):
-    interpret = (not _on_tpu()) if interpret is None else interpret
     return matmul_rs_fused(x, w, axis_name, n_chunks=n_chunks,
                            interpret=interpret)
 
@@ -146,7 +138,6 @@ def pk_matmul_rs(x, w, axis_name, *, n_chunks=1, interpret=None):
 def pk_matmul_ar(x, w, axis_name, *, n_chunks=1, interpret=None):
     """Fused GEMM×all-reduce: one kernel (RS ring + in-kernel gather of the
     reduced blocks). Returns (m, n) fp32."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
     out = matmul_ar_fused(x, w, axis_name, n_chunks=n_chunks,
                           interpret=interpret)
     return out.reshape(-1, w.shape[1])

@@ -38,7 +38,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import compat
 from repro.core.comms import collective_id
-from repro.core.schedule import fit_chunks
+from repro.core.schedule import fit_chunks, row_tile
 
 
 # ---------------------------------------------------------------------------
@@ -148,15 +148,17 @@ def _ag_kernel(x_ref, out_ref, send_sem, recv_sem, copy_sem, *,
     lax.fori_loop(0, n_dev - 1, hop, 0)
 
 
-def ring_all_gather(x, axis_name: str, *, mesh=None, n_chunks: int = 1,
-                    interpret=True):
+def ring_all_gather(x, axis_name: str, *, n_chunks: int = 1,
+                    interpret: bool | None = None):
     """x: (blk, ...) local shard -> (n_dev, blk, ...) full array, via one-way
     RDMA hops into pre-allocated slots. Call inside shard_map. ``n_chunks``
     splits each hop's payload into row sub-chunks (largest-divisor fallback
     via ``fit_chunks`` — never a shape constraint); results are bit-identical
     to the 1-chunk schedule."""
     n_dev = compat.axis_size(axis_name)
-    n_chunks = fit_chunks(x.shape[0], n_chunks) if x.ndim else 1
+    n_chunks = (fit_chunks(x.shape[0], n_chunks,
+                           align=row_tile(x.dtype.itemsize))
+                if x.ndim else 1)
     chunk_rows = (x.shape[0] // n_chunks) if x.ndim else 0
     out_shape = jax.ShapeDtypeStruct((n_dev, *x.shape), x.dtype)
     return pl.pallas_call(
@@ -168,8 +170,9 @@ def ring_all_gather(x, axis_name: str, *, mesh=None, n_chunks: int = 1,
         scratch_shapes=[pltpu.SemaphoreType.DMA((n_dev - 1, n_chunks)),
                         pltpu.SemaphoreType.DMA((n_dev - 1, n_chunks)),
                         pltpu.SemaphoreType.DMA],
-        compiler_params=compat.CompilerParams(collective_id=collective_id("ring_all_gather")),
-        interpret=compat.interpret_params() if interpret else False,
+        compiler_params=compat.CompilerParams(
+            collective_id=collective_id("ring_all_gather")),
+        interpret=compat.kernel_interpret(interpret),
     )(x)
 
 
@@ -240,7 +243,7 @@ def _rs_kernel(x_ref, out_ref, landing, acc_v, tmp_v, send_sem, recv_sem,
 
 
 def ring_reduce_scatter(x, axis_name: str, *, n_chunks: int = 1,
-                        interpret=True):
+                        interpret: bool | None = None):
     """x: (n_dev, blk, ...) per-destination partials -> (blk, ...) reduced
     shard for this device. Accumulate-and-forward ring; landing buffers are
     double-buffered PGL scratch slots (no staging copies). ``n_chunks``
@@ -249,24 +252,30 @@ def ring_reduce_scatter(x, axis_name: str, *, n_chunks: int = 1,
     the 1-chunk schedule."""
     n_dev = compat.axis_size(axis_name)
     blk_shape = x.shape[1:]
-    n_chunks = fit_chunks(blk_shape[0], n_chunks) if blk_shape else 1
+    n_chunks = (fit_chunks(blk_shape[0], n_chunks,
+                           align=row_tile(x.dtype.itemsize))
+                if blk_shape else 1)
     chunk_rows = (blk_shape[0] // n_chunks) if blk_shape else 0
     return pl.pallas_call(
         functools.partial(_rs_kernel, axis_name=axis_name, n_dev=n_dev,
                           n_chunks=n_chunks, chunk_rows=chunk_rows),
         in_specs=[pl.BlockSpec(memory_space=compat.ANY)],
-        out_specs=pl.BlockSpec(memory_space=compat.ANY),
-        out_shape=jax.ShapeDtypeStruct(blk_shape, x.dtype),
-        scratch_shapes=[compat.hbm_scratch((2, *blk_shape), x.dtype),
-                        pltpu.VMEM(blk_shape, x.dtype),
+        # the landing double buffer is a second (discarded) output: remote
+        # DMAs need an HBM destination, and Mosaic scratch is VMEM/SMEM only
+        out_specs=(pl.BlockSpec(memory_space=compat.ANY),
+                   pl.BlockSpec(memory_space=compat.ANY)),
+        out_shape=(jax.ShapeDtypeStruct(blk_shape, x.dtype),
+                   jax.ShapeDtypeStruct((2, *blk_shape), x.dtype)),
+        scratch_shapes=[pltpu.VMEM(blk_shape, x.dtype),
                         pltpu.VMEM(blk_shape, x.dtype),
                         pltpu.SemaphoreType.DMA((n_dev - 1, n_chunks)),
                         pltpu.SemaphoreType.DMA((n_dev - 1, n_chunks)),
                         pltpu.SemaphoreType.REGULAR((2,)),
                         pltpu.SemaphoreType.DMA],
-        compiler_params=compat.CompilerParams(collective_id=collective_id("ring_reduce_scatter")),
-        interpret=compat.interpret_params() if interpret else False,
-    )(x)
+        compiler_params=compat.CompilerParams(
+            collective_id=collective_id("ring_reduce_scatter")),
+        interpret=compat.kernel_interpret(interpret),
+    )(x)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +290,7 @@ def _p2p_kernel(x_ref, out_ref, send_sem, recv_sem, *, axis_name, n_dev):
     rdma.wait()
 
 
-def p2p_ring_shift(x, axis_name: str, *, interpret=True):
+def p2p_ring_shift(x, axis_name: str, *, interpret: bool | None = None):
     """Single-hop one-way RDMA (store_async) to the right neighbor."""
     n_dev = compat.axis_size(axis_name)
     return pl.pallas_call(
@@ -290,6 +299,7 @@ def p2p_ring_shift(x, axis_name: str, *, interpret=True):
         out_specs=pl.BlockSpec(memory_space=compat.ANY),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=compat.CompilerParams(collective_id=collective_id("p2p_ring_shift")),
-        interpret=compat.interpret_params() if interpret else False,
+        compiler_params=compat.CompilerParams(
+            collective_id=collective_id("p2p_ring_shift")),
+        interpret=compat.kernel_interpret(interpret),
     )(x)

@@ -31,6 +31,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro import compat
 from repro.configs import get_config
 from repro.configs.base import RunConfig, ServeConfig
 from repro.launch import specs as SP
@@ -63,10 +64,7 @@ def build_engine(arch: str, *, reduced: bool = True, mesh_shape=None,
     run = RunConfig(**kw)
     rules = ShardingRules(mesh, run) if mesh is not None else None
     tmpl = T.param_template(cfg, run, rules)
-    params = T.init_params(tmpl, jax.random.PRNGKey(seed), cfg.d_model)
-    if rules is not None:
-        params = jax.tree.map(jax.device_put, params,
-                              SP.named(mesh, T.param_specs(tmpl)))
+    params = SP.init_params(tmpl, seed, cfg.d_model, mesh)
     if serve is None:
         ssm = any(sp.mixer == "mamba" for sp in cfg.layer_pattern())
         serve = ServeConfig(exact_buckets=ssm)
@@ -243,6 +241,7 @@ def main():
                     help="fleet: snapshot/rejoin checkpoint directory")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compat.enable_compile_cache()
 
     if args.mode == "static":
         generate(args.arch, reduced=args.reduced, batch=args.batch,

@@ -5,6 +5,7 @@ shardable, zero allocation (assignment MULTI-POD DRY-RUN §2).
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -81,3 +82,14 @@ def named(mesh: Mesh, tree):
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s),
         tree, is_leaf=lambda x: isinstance(x, P))
+
+
+def init_params(tmpl, seed: int, d_model: int, mesh: Mesh | None = None):
+    """``T.init_params`` as one jitted program whose outputs land directly
+    in the template's shardings on ``mesh`` (the default device when None).
+    Eager init would first build the whole unsharded model, f32 draws
+    included, on one device."""
+    kw = {} if mesh is None else {
+        "out_shardings": named(mesh, T.param_specs(tmpl))}
+    init = functools.partial(T.init_params, tmpl, d_model=d_model)
+    return jax.jit(init, **kw)(jax.random.PRNGKey(seed))

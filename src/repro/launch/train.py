@@ -11,6 +11,7 @@ import argparse
 
 import jax
 
+from repro import compat
 from repro.configs import get_config
 from repro.configs.base import RunConfig
 from repro.core.template import render_plans
@@ -54,10 +55,7 @@ def build_and_train(arch: str, *, steps: int, reduced: bool, mesh_shape,
                                         seq=seq)))
 
     tmpl = T.param_template(cfg, run, rules)
-    params = T.init_params(tmpl, jax.random.PRNGKey(seed), cfg.d_model)
-    if rules is not None:
-        shardings = SP.named(mesh, T.param_specs(tmpl))
-        params = jax.tree.map(jax.device_put, params, shardings)
+    params = SP.init_params(tmpl, seed, cfg.d_model, mesh)
 
     opt = AdamW(lr=warmup_cosine(lr, max(10, steps // 20), steps),
                 weight_decay=0.01)
@@ -116,6 +114,7 @@ def main():
                          "quantized sub-chunks + f32 scales (int8_sr adds "
                          "stochastic rounding); default full precision")
     args = ap.parse_args()
+    compat.enable_compile_cache()
     build_and_train(args.arch, steps=args.steps, reduced=args.reduced,
                     mesh_shape=args.mesh_shape, mesh_axes=args.mesh_axes,
                     batch=args.batch, seq=args.seq, ckpt_dir=args.ckpt_dir,

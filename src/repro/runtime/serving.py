@@ -1071,20 +1071,44 @@ class ServingEngine:
             if self.slots[slot].remaining == 0:
                 self._retire(slot)
 
+    def _prefill_inputs(self, bucket: int, prompts) -> tuple:
+        """(tokens, lens) of one prefill group, right-padded to ``bucket``;
+        rows past ``prompts`` are inert 1-token pads."""
+        g = self.serve.prefill_batch
+        tokens = np.zeros((g, bucket), np.int32)
+        lens = np.ones((g,), np.int32)
+        for i, p in enumerate(prompts):
+            tokens[i, :len(p)] = p
+            lens[i] = len(p)
+        return jnp.asarray(tokens), jnp.asarray(lens)
+
+    def prefill_logits(self, prompts: Sequence[Sequence[int]],
+                       params=None) -> np.ndarray:
+        """Last-position logits ``(len(prompts), vocab)`` in f32 from this
+        engine's own jitted prefill program for the prompts' bucket. The
+        cache it builds is dropped: no slot, queue or counter changes.
+        ``params`` (default: the engine's) runs the same program on another
+        copy of the weights, e.g. one placed on another backend."""
+        if not 1 <= len(prompts) <= self.serve.prefill_batch:
+            raise ValueError(f"1..{self.serve.prefill_batch} prompts per "
+                             f"prefill group; got {len(prompts)}")
+        bucket = self.serve.bucket_for(max(len(p) for p in prompts))
+        fn = self._prefill_fn(bucket)
+        tokens, lens = self._prefill_inputs(bucket, prompts)
+        logits, _ = fn(self.params if params is None else params,
+                       self._sharded_zeros(self._prefill_tmpls[bucket]),
+                       tokens, lens)
+        return np.asarray(logits[:len(prompts), -1, :self.cfg.vocab_size],
+                          np.float32)
+
     def _prefill(self, bucket: int, reqs: list[Request],
                  slot_ids: list[int]) -> None:
-        g = self.serve.prefill_batch
         fn = self._prefill_fn(bucket)
         if self._current_fault is not None:
             fn = self._faulted_fn(("prefill", bucket), self._current_fault)
-        tokens = np.zeros((g, bucket), np.int32)
-        lens = np.ones((g,), np.int32)           # inert pad slots: 1 token
-        for i, r in enumerate(reqs):
-            tokens[i, :len(r.prompt)] = r.prompt
-            lens[i] = len(r.prompt)
+        tokens, lens = self._prefill_inputs(bucket, [r.prompt for r in reqs])
         gcache = self._sharded_zeros(self._prefill_tmpls[bucket])
-        logits, gcache = fn(self.params, gcache, jnp.asarray(tokens),
-                            jnp.asarray(lens))
+        logits, gcache = fn(self.params, gcache, tokens, lens)
         finite = self._finite_rows(logits)
         first = self._greedy(logits)
         # only finite rows scatter into the live cache and open slots —

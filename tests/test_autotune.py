@@ -502,6 +502,18 @@ def test_analytic_policy_ignores_tables(mesh4, table):
 # Staleness (table age + spot-probe drift)
 # ---------------------------------------------------------------------------
 
+def test_unknown_hw_raises_instead_of_assuming_v5e(table):
+    """A table stamped for hardware with no HardwareSpec is an error, not a
+    silent fall-back to the v5e peaks."""
+    from repro.core import costmodel as cm
+    assert cm.spec_by_name("TPU_V5E") is cm.TPU_V5E
+    foreign = dataclasses.replace(
+        table, fingerprint=dataclasses.replace(table.fingerprint,
+                                               hw="tpu_v7x"))
+    with pytest.raises(KeyError, match="tpu_v7x"):
+        autotune.staleness(foreign, probe=True)
+
+
 def test_table_age_days_parses_created(table):
     t = dataclasses.replace(table, created="2020-01-01T00:00:00")
     assert autotune.table_age_days(t) > 365

@@ -107,21 +107,11 @@ def test_registry_and_availability(ctx):
         assert "bulk" in backends, op
         avail = ctx.available_backends(op)
         assert set(avail) <= set(backends)
-        if not compat.tpu_kernels_supported():
-            assert "fused" not in avail
 
 
 def test_unknown_backend_raises(ctx):
     with pytest.raises(ValueError, match="no backend"):
         ctx.psum(jnp.ones((4,)), backend="nope")
-
-
-@pytest.mark.skipif(compat.tpu_kernels_supported(),
-                    reason="fused kernels available here")
-def test_fused_unavailable_raises_cleanly(ctx):
-    with pytest.raises(NotImplementedError, match="fused"):
-        ctx.all_gather_matmul(jnp.ones((8, 8)), jnp.ones((8, 8)),
-                              backend="fused")
 
 
 # ---------------------------------------------------------------------------

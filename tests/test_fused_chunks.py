@@ -30,10 +30,6 @@ from repro.core.schedule import choose_gemm_chunks, fit_chunks
 
 N = 4
 
-requires_interpret = pytest.mark.skipif(
-    not compat.tpu_kernels_supported(),
-    reason="no TPU backend and no pltpu.InterpretParams in this JAX")
-
 
 def _synthetic(fingerprint, rows, **corr):
     corrections = {"ici_bandwidth": 1e8, "remote_sync_s": 1e-4,
@@ -102,6 +98,19 @@ def test_fit_chunks_degrades_never_rejects():
     assert fit_chunks(16, 3) == 2       # largest divisor <= request
     assert fit_chunks(7, 4) == 1
     assert fit_chunks(0, 4) == 1
+
+
+@pytest.mark.parametrize("extent,req,align,want", [
+    (64, 4, 16, 4),     # 16-row chunks: on the bf16 tiling
+    (64, 8, 16, 4),     # 8-row chunks would be off it
+    (8, 4, 2, 4),
+    (8, 4, 16, 1),      # no aligned split: one whole-extent chunk
+    (48, 8, 16, 3),     # largest count that divides AND stays aligned
+])
+def test_fit_chunks_keeps_rows_on_the_tiling(extent, req, align, want):
+    from repro.core.schedule import row_tile
+    assert row_tile(2) == 16 and row_tile(4) == 8 and row_tile(1) == 32
+    assert fit_chunks(extent, req, align=align) == want
 
 
 # ---------------------------------------------------------------------------
@@ -281,12 +290,12 @@ def sm(mesh4):
     return partial(compat.shard_map, mesh=mesh4, check_vma=False)
 
 
-@requires_interpret
 @pytest.mark.parametrize("n_chunks", [1, 2, 4, 3])
 def test_ag_matmul_chunked(sm, n_chunks):
     from repro.kernels import ref
     from repro.kernels.collective_matmul import ag_matmul_fused
-    m_loc, k, n_out = 16, 32, 24
+    # chunk rows stay multiples of the f32 row tile (8) up to 4 chunks
+    m_loc, k, n_out = 32, 32, 24
     x = jax.random.normal(jax.random.PRNGKey(0), (N * m_loc, k), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (k, n_out), jnp.float32)
 
@@ -305,12 +314,11 @@ def test_ag_matmul_chunked(sm, n_chunks):
     assert np.array_equal(got, run(1))
 
 
-@requires_interpret
 @pytest.mark.parametrize("n_chunks", [1, 2, 4, 3])
 def test_matmul_rs_chunked(sm, n_chunks):
     from repro.kernels import ref
     from repro.kernels.collective_matmul import matmul_rs_fused
-    m, k_loc, n_out = 16, 8, 24
+    m, k_loc, n_out = N * 32, 8, 24     # row blocks of 32: 4 chunks of 8
     x = jax.random.normal(jax.random.PRNGKey(0), (m, N * k_loc), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (N * k_loc, n_out),
                           jnp.float32)
@@ -327,12 +335,11 @@ def test_matmul_rs_chunked(sm, n_chunks):
     assert np.array_equal(got, run(1))
 
 
-@requires_interpret
 @pytest.mark.parametrize("n_chunks", [1, 2, 4, 3])
 def test_matmul_ar_chunked(sm, n_chunks):
     from repro.kernels import ref
     from repro.kernels.collective_matmul import matmul_ar_fused
-    m, k_loc, n_out = 16, 8, 24
+    m, k_loc, n_out = N * 32, 8, 24     # row blocks of 32: 4 chunks of 8
     x = jax.random.normal(jax.random.PRNGKey(0), (m, N * k_loc), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (N * k_loc, n_out),
                           jnp.float32)
@@ -351,19 +358,18 @@ def test_matmul_ar_chunked(sm, n_chunks):
     assert np.array_equal(got, run(1))
 
 
-@requires_interpret
 @pytest.mark.parametrize("n_chunks", [2, 4, 3])
 def test_ring_collectives_chunked(sm, n_chunks):
     from repro.kernels import ref
     from repro.kernels.pk_comm import ring_all_gather, ring_reduce_scatter
-    x = jax.random.normal(jax.random.PRNGKey(0), (N, 8, 16), jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (N, 32, 16), jnp.float32)
     f = jax.jit(sm(
         lambda x: ring_all_gather(x[0], "x", n_chunks=n_chunks)[None],
         in_specs=P("x"), out_specs=P("x")))
     got = np.asarray(f(x))
     for d in range(N):
         np.testing.assert_allclose(got[d], np.asarray(x))
-    xg = jax.random.normal(jax.random.PRNGKey(1), (N, N, 8, 16), jnp.float32)
+    xg = jax.random.normal(jax.random.PRNGKey(1), (N, N, 32, 16), jnp.float32)
     g = jax.jit(sm(
         lambda x: ring_reduce_scatter(x[0], "x", n_chunks=n_chunks)[None],
         in_specs=P("x"), out_specs=P("x")))

@@ -26,6 +26,26 @@ def _setup(arch, mesh, run):
     return cfg, rules, params
 
 
+def test_jitted_init_lands_in_the_template_shardings(mesh22):
+    """launch.specs.init_params draws the weights in one program whose
+    outputs already carry the template's shardings (no whole unsharded copy
+    on one device first) and, on the CPU, equal the eager init bit for
+    bit."""
+    from repro.launch.specs import init_params as sharded_init
+    run = RunConfig(dp_axes=("data",), fsdp=True, pk_overlap=True)
+    cfg = get_config("tinyllama-1.1b").reduced()
+    tmpl = param_template(cfg, run, ShardingRules(mesh22, run))
+    got = sharded_init(tmpl, 0, cfg.d_model, mesh22)
+    eager = init_params(tmpl, jax.random.PRNGKey(0), cfg.d_model)
+
+    def check(g, e, s):
+        assert g.sharding.is_equivalent_to(NamedSharding(mesh22, s), g.ndim)
+        assert (g.shape, g.dtype) == (e.shape, e.dtype)
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(e, np.float32))
+    jax.tree.map(check, got, eager, param_specs(tmpl))
+
+
 def _batch(cfg, b, s):
     batch = {"tokens": jnp.zeros((b, s), jnp.int32),
              "targets": jnp.ones((b, s), jnp.int32),

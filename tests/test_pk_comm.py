@@ -13,12 +13,6 @@ import pytest
 from repro import compat
 from jax.sharding import PartitionSpec as P
 
-# The communication kernels need cross-device semaphore/remote-DMA emulation
-# (pltpu.InterpretParams) off-TPU; skip cleanly on JAX builds without it.
-pytestmark = pytest.mark.skipif(
-    not compat.tpu_kernels_supported(),
-    reason="no TPU backend and no pltpu.InterpretParams in this JAX")
-
 from repro.kernels import ref
 from repro.kernels.collective_matmul import (ag_matmul_fused, matmul_ar_fused,
                                              matmul_rs_fused)

@@ -80,6 +80,27 @@ def test_engine_no_mesh_dense_fallback():
     assert done[1].tokens == ref[0].tokens
 
 
+def test_prefill_logits_are_the_served_first_tokens():
+    """prefill_logits runs the engine's own prefill program, changes no
+    engine state, and gives the same logits for another copy of the
+    weights; its argmax is the first token the engine then serves."""
+    eng = _engine(None, SERVE)
+    rng = np.random.RandomState(3)
+    trace = [tuple(int(t) for t in rng.randint(0, eng.cfg.vocab_size, n))
+             for n in (5, 7)]                    # one bucket (8), one group
+    logits = eng.prefill_logits(trace)
+    assert logits.shape == (2, eng.cfg.vocab_size)
+    assert logits.dtype == np.float32 and np.isfinite(logits).all()
+    assert not eng.events and not eng.completions
+    copy = jax.tree.map(jnp.copy, eng.params)
+    np.testing.assert_array_equal(eng.prefill_logits(trace, params=copy),
+                                  logits)
+    done = {c.rid: c.tokens for c in eng.run(trace)}
+    assert [done[0][0], done[1][0]] == logits.argmax(-1).tolist()
+    with pytest.raises(ValueError, match="prompts per prefill group"):
+        eng.prefill_logits(trace * 2)
+
+
 # ---------------------------------------------------------------------------
 # Admission / eviction determinism under a scripted trace
 # ---------------------------------------------------------------------------

@@ -1,8 +1,10 @@
 """End-to-end behaviour tests: the public train/serve paths on an emulated
 mesh with all substrates active (PK overlap, FSDP, checkpointing)."""
 
-import numpy as np
+from pathlib import Path
 
+import numpy as np
+import pytest
 
 
 def test_end_to_end_train_on_mesh(tmp_path):
@@ -61,3 +63,36 @@ def test_hybrid_arch_trains_on_mesh(tmp_path):
         mesh_axes=("data", "model"), batch=4, seq=32,
         ckpt_dir=str(tmp_path), log_every=1, ckpt_every=100)
     assert np.isfinite(log[-1]["loss"])
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it
+    the cache sits at the fixed <repo>/.jax_cache."""
+    import jax
+
+    from repro import compat
+    was = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        assert compat.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo_cache = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert compat.enable_compile_cache() == repo_cache
+        assert jax.config.jax_compilation_cache_dir == repo_cache
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_chip_smoke_refuses_without_tpu(capsys):
+    """The chip smoke stops before any work when JAX finds no TPU: no CPU
+    run stands in for the chip."""
+    import importlib.util
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    with pytest.raises(SystemExit) as e:
+        smoke.check_device(1)
+    assert e.value.code == 2
+    assert "no TPU found" in capsys.readouterr().err
