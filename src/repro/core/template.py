@@ -398,10 +398,17 @@ class Island:
         return comm_context(self.run, self.axis, mesh=self.mesh, **kw)
 
     def __call__(self, **arrays):
+        """Run the island: its ``shard_map`` or its dense reference, either
+        one under ``jax.named_scope(self.name)``, so every op it lowers to
+        carries the island's name in its ``op_name`` metadata."""
         if set(arrays) != set(self.inputs) and self.fallback_reason() is None:
             raise TypeError(
                 f"island {self.name!r} declared inputs "
                 f"{sorted(self.inputs)}, got {sorted(arrays)}")
+        with jax.named_scope(self.name):
+            return self._run(**arrays)
+
+    def _run(self, **arrays):
         reason = self.fallback_reason()
         if reason is not None:
             if self.reference is None:

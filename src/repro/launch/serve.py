@@ -287,10 +287,13 @@ def main():
     done = eng.run(trace)
     st = eng.stats()
     print(f"[serve] {args.arch}: {len(done)} requests, "
-          f"{st['tokens_generated']} tokens in {st['wall_s']:.2f}s "
-          f"({st['tokens_per_s']:.1f} tok/s; "
+          f"{st['tokens_generated']} tokens in {st['wall_s']:.2f}s of "
+          f"engine steps ({st['tokens_per_s']:.1f} tok/s over step time; "
           f"{st['prefill_steps']} prefill + {st['decode_steps']} decode "
           f"steps; buckets jitted: {st['compiled_buckets']})")
+    print("[serve] step time by phase: " + ", ".join(
+        f"{n} {sec:.3f}s" for n, sec in sorted(st["phase_s"].items(),
+                                               key=lambda kv: -kv[1])))
     cs = st["cache"]
     line = (f"[cache] layout={cs['layout']} kv={cs['kv_dtype']} "
             f"hbm={cs['hbm_bytes']/1e6:.1f}MB "

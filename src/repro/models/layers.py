@@ -55,6 +55,7 @@ def _wire_bytes(cfg: ArchConfig, run: RunConfig) -> int:
 # Norms / activations
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("norm")
 def rms_norm(w, x, eps: float = 1e-5):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     y = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
@@ -305,18 +306,19 @@ def attention_block(p, x, cfg: ArchConfig, run: RunConfig,
     """
     b, s, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, hq, hd)
-    q = q.transpose(0, 2, 1, 3)
-    if cross_kv is None:
-        k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
-        v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
-    else:
-        k, v = cross_kv
-    if positions is None:
-        positions = jnp.arange(s)
-    if cross_kv is None:                         # RoPE on self-attention only
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, hq, hd)
+        q = q.transpose(0, 2, 1, 3)
+        if cross_kv is None:
+            k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
+            v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
+        else:
+            k, v = cross_kv
+        if positions is None:
+            positions = jnp.arange(s)
+        if cross_kv is None:                     # RoPE on self-attention only
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
 
     win = cfg.sliding_window if cross_kv is None else None
 
@@ -515,10 +517,10 @@ def decode_attention(p, x, cache_k, cache_v, pos, cfg: ArchConfig,
     """One-token decode with KV cache.
 
     x: (B, 1, d); cache_k/v: (B, Hkv, S_max, hd); pos: scalar current index.
-    Returns (out (B,1,d), new_k, new_v). If run.decode_seq_shard, attention
-    over the sharded cache runs through the decode Island — the SP serving
-    path (DESIGN §4); the template's fallback predicate routes single-device
-    or indivisible meshes to the dense cache path.
+    Returns (out (B,1,d), new_k, new_v). Self-attention runs through the
+    decode Island: with run.decode_seq_shard over the sharded cache — the SP
+    serving path (DESIGN §4) — and otherwise, or on a single-device or
+    indivisible mesh, through the island's dense reference.
 
     int8 mode: ``cache_k`` is int8 and ``k_scale``/``v_scale`` carry the
     per-(token, head) f32 scale planes — the new token is quantized on
@@ -528,57 +530,40 @@ def decode_attention(p, x, cache_k, cache_v, pos, cfg: ArchConfig,
     b, _, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     quant = k_scale is not None
-    cache_k_in, cache_v_in = cache_k, cache_v
-    q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, 1, hq, hd).transpose(0, 2, 1, 3)
-    if cross_kv is None:
-        k_new = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, 1, hkv, hd).transpose(0, 2, 1, 3)
-        v_new = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, 1, hkv, hd).transpose(0, 2, 1, 3)
-        # scalar pos = lockstep decode; (B,) pos = per-slot positions (the
-        # serving engine's mixed pool) — RoPE takes the (B, 1) form directly
-        positions = pos[:, None] if jnp.ndim(pos) else jnp.full((1,), pos)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k_new = apply_rope(k_new, positions, cfg.rope_theta)
-        kv_len = pos + 1          # cache write is deferred (see below)
-    else:
-        k_att, v_att = cross_kv
-        kv_len = k_att.shape[2]
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, 1, hq, hd).transpose(0, 2, 1, 3)
+        if cross_kv is None:
+            k_new = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, 1, hkv, hd).transpose(0, 2, 1, 3)
+            v_new = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, 1, hkv, hd).transpose(0, 2, 1, 3)
+            # scalar pos = lockstep decode; (B,) pos = per-slot positions
+            # (the serving engine's mixed pool) — RoPE takes the (B, 1)
+            # form directly
+            positions = pos[:, None] if jnp.ndim(pos) else jnp.full((1,), pos)
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k_new = apply_rope(k_new, positions, cfg.rope_theta)
 
-    window = cfg.sliding_window if cross_kv is None else None
-    if rules is not None and run.decode_seq_shard and cross_kv is None:
-        island = decode_island(cfg, run, rules, b, cache_k_in.shape[2],
-                               long_ctx=long_ctx, pos=pos, kv_len=kv_len,
-                               window=window, quant=quant)
+    if cross_kv is None:
+        # the island's dense reference is the single-device cache path
+        island = decode_island(cfg, run, rules, b, cache_k.shape[2],
+                               long_ctx=long_ctx, pos=pos, kv_len=pos + 1,
+                               window=cfg.sliding_window, quant=quant)
         kw = {"pos": pos} if jnp.ndim(pos) else {}
         if quant:
             kw["cache_ks"], kw["cache_vs"] = k_scale, v_scale
             o, cache_k, cache_v, k_scale, v_scale = island(
-                q=q, cache_k=cache_k_in, cache_v=cache_v_in, k_new=k_new,
+                q=q, cache_k=cache_k, cache_v=cache_v, k_new=k_new,
                 v_new=v_new, **kw)
         else:
-            o, cache_k, cache_v = island(q=q, cache_k=cache_k_in,
-                                         cache_v=cache_v_in, k_new=k_new,
+            o, cache_k, cache_v = island(q=q, cache_k=cache_k,
+                                         cache_v=cache_v, k_new=k_new,
                                          v_new=v_new, **kw)
     else:
-        if cross_kv is None:
-            if quant:
-                qk, sk = _kv_quantize(k_new)
-                qv, sv = _kv_quantize(v_new)
-                cache_k = _cache_write(cache_k_in, qk, pos)
-                cache_v = _cache_write(cache_v_in, qv, pos)
-                k_scale = _cache_write(k_scale, sk, pos)
-                v_scale = _cache_write(v_scale, sv, pos)
-                k_att = _kv_dequantize(cache_k, k_scale, q.dtype)
-                v_att = _kv_dequantize(cache_v, v_scale, q.dtype)
-            else:
-                cache_k = _cache_write(cache_k_in, k_new, pos)
-                cache_v = _cache_write(cache_v_in, v_new, pos)
-                k_att, v_att = cache_k, cache_v
-        o = _full_attention(q, k_att, v_att, causal=False, window=window,
-                            q_offset=0, kv_len=kv_len)
-        # causal handled via kv_len (all cached positions <= pos are visible);
-        # SWA via window against kv_len-1.
-    o = o.transpose(0, 2, 1, 3).reshape(b, 1, hq * hd)
-    out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
+        k_att, v_att = cross_kv
+        o = _full_attention(q, k_att, v_att, causal=False, window=None,
+                            q_offset=0, kv_len=k_att.shape[2])
+    with jax.named_scope("attn_out"):
+        o = o.transpose(0, 2, 1, 3).reshape(b, 1, hq * hd)
+        out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
     if cross_kv is not None:
         return out, None, None
     if quant:
@@ -676,12 +661,13 @@ def prefill_attention_block(p, x, cache_k, cache_v, cfg: ArchConfig,
     b, s, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     quant = k_scale is not None
-    q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, hq, hd).transpose(0, 2, 1, 3)
-    k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
-    v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
-    positions = jnp.arange(s)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, hq, hd).transpose(0, 2, 1, 3)
+        k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
+        v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
+        positions = jnp.arange(s)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     if rules is not None:
         q = constrain(q, rules, rules.act_bhsd(hq))
     if quant:
@@ -692,10 +678,11 @@ def prefill_attention_block(p, x, cache_k, cache_v, cfg: ArchConfig,
     else:
         k_att, v_att = k, v
     win = cfg.sliding_window
-    if s >= XLA_ATTN_CHUNK_THRESHOLD:
-        o = _chunked_attention(q, k_att, v_att, causal=True, window=win)
-    else:
-        o = _full_attention(q, k_att, v_att, causal=True, window=win)
+    with jax.named_scope("prefill_attn"):
+        if s >= XLA_ATTN_CHUNK_THRESHOLD:
+            o = _chunked_attention(q, k_att, v_att, causal=True, window=win)
+        else:
+            o = _full_attention(q, k_att, v_att, causal=True, window=win)
     write = prefill_write_island(cfg, run, rules, b, s, quant=quant)
     if quant:
         new_k, k_scale = write(cache=cache_k, scale=k_scale, new=qk,
@@ -1020,19 +1007,21 @@ def paged_decode_attention(p, x, pool_k, pool_v, bt, pos, cfg: ArchConfig,
     b, _, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     quant = k_scale is not None
-    q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, 1, hq, hd).transpose(0, 2, 1, 3)
-    k_new = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, 1, hkv, hd).transpose(0, 2, 1, 3)
-    v_new = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, 1, hkv, hd).transpose(0, 2, 1, 3)
-    positions = pos[:, None]
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k_new = apply_rope(k_new, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, 1, hq, hd).transpose(0, 2, 1, 3)
+        k_new = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, 1, hkv, hd).transpose(0, 2, 1, 3)
+        v_new = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, 1, hkv, hd).transpose(0, 2, 1, 3)
+        positions = pos[:, None]
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k_new = apply_rope(k_new, positions, cfg.rope_theta)
     island = paged_decode_island(cfg, run, rules, b, pool_k.shape[2],
                                  window=cfg.sliding_window, quant=quant)
     kw = {"pool_ks": k_scale, "pool_vs": v_scale} if quant else {}
     res = island(q=q, pool_k=pool_k, pool_v=pool_v, k_new=k_new,
                  v_new=v_new, bt=bt, pos=pos, **kw)
-    o = res[0].transpose(0, 2, 1, 3).reshape(b, 1, hq * hd)
-    out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
+    with jax.named_scope("attn_out"):
+        o = res[0].transpose(0, 2, 1, 3).reshape(b, 1, hq * hd)
+        out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
     return (out, *res[1:])
 
 
@@ -1049,12 +1038,13 @@ def paged_prefill_attention_block(p, x, pool_k, pool_v, bt, chunk_start,
     b, s, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     quant = k_scale is not None
-    q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, hq, hd).transpose(0, 2, 1, 3)
-    k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
-    v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
-    positions = chunk_start + jnp.arange(s)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bsd,dh->bsh", x, p["wq"]).reshape(b, s, hq, hd).transpose(0, 2, 1, 3)
+        k = jnp.einsum("bsd,dh->bsh", x, p["wk"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
+        v = jnp.einsum("bsd,dh->bsh", x, p["wv"]).reshape(b, s, hkv, hd).transpose(0, 2, 1, 3)
+        positions = chunk_start + jnp.arange(s)
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
     if rules is not None:
         q = constrain(q, rules, rules.act_bhsd(hq))
     island = paged_prefill_island(cfg, run, rules, b, s, pool_k.shape[2],
@@ -1414,6 +1404,7 @@ def lm_loss(p, x, targets, weights, cfg: ArchConfig, run: RunConfig,
     return jnp.sum(tot) / jnp.maximum(jnp.sum(cnt), 1.0)
 
 
+@jax.named_scope("head")
 def lm_logits(p, x, rules: ShardingRules | None):
     """Full logits for serving (B, S, V)."""
     logits = jnp.einsum("bsd,dv->bsv", x, p["lm_head"]).astype(jnp.float32)

@@ -414,6 +414,24 @@ def cache_template(cfg: ArchConfig, run: RunConfig, rules: ShardingRules | None,
     return tree
 
 
+def _scan_cache(body, x, blocks, cache_blocks, cfg: ArchConfig,
+                run: RunConfig):
+    """Apply ``body(x, (period_params, period_cache)) -> (x, new_cache)``
+    over the periods, threading each period's cache slice through as scan
+    input and output (unrolled without ``run.scan_layers``). Named
+    ``cache_scan``: the copies and slab updates of that threading carry it
+    in their ``op_name`` metadata."""
+    with jax.named_scope("cache_scan"):
+        if run.scan_layers:
+            return lax.scan(body, x, (blocks, cache_blocks))
+        new_list = []
+        for i in range(cfg.n_periods):
+            x, nc = body(x, jax.tree.map(lambda a: a[i],
+                                         (blocks, cache_blocks)))
+            new_list.append(nc)
+        return x, jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
+
+
 def decode_step(params, cache, tokens, cfg: ArchConfig, run: RunConfig,
                 rules: ShardingRules | None, *, long_ctx: bool = False):
     """One decode step. tokens: (B, 1) int32. Returns (logits, new_cache).
@@ -471,15 +489,8 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, run: RunConfig,
                 x = x + h
         return x, new_cache
 
-    if not run.scan_layers:
-        new_list = []
-        for i in range(cfg.n_periods):
-            x, nc = body(x, jax.tree.map(lambda a: a[i],
-                                         (params["blocks"], cache["blocks"])))
-            new_list.append(nc)
-        new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
-    else:
-        x, new_blocks = lax.scan(body, x, (params["blocks"], cache["blocks"]))
+    x, new_blocks = _scan_cache(body, x, params["blocks"], cache["blocks"],
+                                cfg, run)
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     head = params["lm_head"] if "lm_head" in params else params["embed"].T
     logits = L.lm_logits({"lm_head": head}, x, rules)
@@ -604,15 +615,8 @@ def prefill_step(params, cache, tokens, prompt_lens, cfg: ArchConfig,
                 x = x + h
         return x, new_cache
 
-    if not run.scan_layers:
-        new_list = []
-        for i in range(cfg.n_periods):
-            x, nc = body(x, jax.tree.map(lambda a: a[i],
-                                         (params["blocks"], cache["blocks"])))
-            new_list.append(nc)
-        new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
-    else:
-        x, new_blocks = lax.scan(body, x, (params["blocks"], cache["blocks"]))
+    x, new_blocks = _scan_cache(body, x, params["blocks"], cache["blocks"],
+                                cfg, run)
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     # per-slot last REAL position only — never the (B, L, V) logits
     idx = jnp.reshape(jnp.asarray(prompt_lens) - 1, (-1, 1, 1))
@@ -684,15 +688,8 @@ def prefill_paged_step(params, cache, tokens, block_tables, prompt_lens,
                 x = x + h
         return x, new_cache
 
-    if not run.scan_layers:
-        new_list = []
-        for i in range(cfg.n_periods):
-            x, nc = body(x, jax.tree.map(lambda a: a[i],
-                                         (params["blocks"], cache["blocks"])))
-            new_list.append(nc)
-        new_blocks = jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
-    else:
-        x, new_blocks = lax.scan(body, x, (params["blocks"], cache["blocks"]))
+    x, new_blocks = _scan_cache(body, x, params["blocks"], cache["blocks"],
+                                cfg, run)
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     # each slot's last real position clamped into this chunk's window — the
     # engine keeps the logits row from the chunk that contains L−1

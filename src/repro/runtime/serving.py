@@ -77,6 +77,7 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig, RunConfig, ServeConfig
 from repro.core.template import IslandPlan, plan_overrides, render_plans
@@ -250,6 +251,16 @@ def serving_plan_record(cfg: ArchConfig, run: RunConfig,
             "buckets": {name: bp.asdict() for name, bp in table.items()}}
 
 
+def _jit_step(fn, plan: str):
+    """``jax.jit`` of one step program, the cache (argument 1) donated,
+    named after its bucket plan (``decode``, ``prefill@<bucket>``,
+    ``prefill@chunk<cl>``): the device trace's module line then reads
+    ``jit_serve_decode``, ``jit_serve_prefill_<bucket>`` or
+    ``jit_serve_prefill_chunk<cl>``."""
+    fn.__name__ = fn.__qualname__ = "serve_" + plan.replace("@", "_")
+    return jax.jit(fn, donate_argnums=(1,))
+
+
 @dataclasses.dataclass
 class _Slot:
     rid: int
@@ -355,9 +366,8 @@ class ServingEngine:
                 slot_pos=True, kv_dtype=self.serve.kv_dtype)
             self.cache = self._sharded_zeros(self._cache_tmpl)
         self._job: _PrefillJob | None = None
-        self._decode_fn = jax.jit(
-            make_serve_step(cfg, self._runs["decode"], rules),
-            donate_argnums=(1,))
+        self._decode_fn = _jit_step(
+            make_serve_step(cfg, self._runs["decode"], rules), "decode")
         self._prefill_fns: dict[int, Any] = {}     # bucket L -> jitted step
         self._prefill_tmpls: dict[int, Any] = {}
         self._static_fns: dict[tuple[int, int], tuple] = {}
@@ -370,7 +380,13 @@ class ServingEngine:
         self.step_kinds: list[str] = []
         self.watchdog = StragglerWatchdog()
         self.step_times: list[float] = []
+        self.last_phases: dict[str, float] = {}   # split of the last step
+        self.phase_s: dict[str, float] = {}       # seconds per phase, summed
         self.tokens_generated = 0
+        # prefill padding: real prompt tokens dispatched, and bucket x rows
+        # dispatched (inert group rows included)
+        self.prefill_tokens = 0
+        self.prefill_slot_tokens = 0
         self._next_rid = 0
         # fleet hooks: original Request per live rid (so a killed replica's
         # in-flight work can be requeued), admission gate, injected delay
@@ -476,9 +492,8 @@ class ServingEngine:
                     run, island_overrides=(
                         self.bucket_plans[name].overrides + self._hov))
             run = self._runs[name]
-            self._prefill_fns[bucket] = jax.jit(
-                make_prefill_cache_step(self.cfg, run, self.rules),
-                donate_argnums=(1,))
+            self._prefill_fns[bucket] = _jit_step(
+                make_prefill_cache_step(self.cfg, run, self.rules), name)
             self._prefill_tmpls[bucket] = T.cache_template(
                 self.cfg, run, self.rules, batch=self.serve.prefill_batch,
                 s_max=self.s_max, slot_pos=True,
@@ -507,16 +522,45 @@ class ServingEngine:
                 self._runs[name] = dataclasses.replace(
                     run, island_overrides=(
                         self.bucket_plans[name].overrides + self._hov))
-            self._prefill_fns[cl] = jax.jit(
+            self._prefill_fns[cl] = _jit_step(
                 make_paged_prefill_step(self.cfg, self._runs[name],
                                         self.rules),
-                donate_argnums=(1,))
+                name)
         return self._prefill_fns[cl]
 
     @property
     def compiled_buckets(self) -> list[int]:
         """Prefill buckets a step has been jitted for (the jit cache)."""
         return sorted(self._prefill_fns)
+
+    def step_programs(self) -> dict[str, Any]:
+        """{module name: ``jax.stages.Compiled``} of the decode step and of
+        each prefill program jitted so far, lowered again at the shapes the
+        engine calls them with, so the compile cache serves them. Their HLO
+        carries the ``op_name`` scopes (islands, ``qkv``, ``norm``,
+        ``head``, ``cache_scan``) that a device trace's op events lack."""
+        g = self.serve.prefill_batch
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        def abstract(tmpl):
+            return jax.tree.map(
+                lambda pd: jax.ShapeDtypeStruct(
+                    pd.shape, pd.dtype, sharding=(
+                        self.rules.named(pd.spec) if self.rules else None)),
+                tmpl, is_leaf=lambda x: isinstance(x, T.PD))
+
+        calls = [(self._decode_fn,
+                  (self.cache, i32(self.serve.max_batch, 1)))]
+        for n, fn in self._prefill_fns.items():
+            calls.append((fn, (self.cache, i32(g, n),
+                               i32(g, self.geom.pages_per_slot), i32(g),
+                               i32(), i32(g)) if self.paged else
+                          (abstract(self._prefill_tmpls[n]), i32(g, n),
+                           i32(g))))
+        return {f"jit_{fn.__name__}": fn.lower(self.params, *args).compile()
+                for fn, args in calls}
 
     # -- runtime health ----------------------------------------------------
 
@@ -588,25 +632,25 @@ class ServingEngine:
             if phase == "decode":
                 run = dataclasses.replace(self._runs["decode"],
                                           comm_fault=fault)
-                self._fault_fns[k] = jax.jit(
+                self._fault_fns[k] = _jit_step(
                     make_serve_step(self.cfg, run, self.rules),
-                    donate_argnums=(1,))
+                    "decode")
             elif phase == "paged":
                 self._paged_prefill_fn(bucket)     # materialize the plan
                 cl = self.serve.prefill_chunk or bucket
                 name = (f"prefill@chunk{cl}" if self.serve.prefill_chunk
                         else f"prefill@{bucket}")
                 run = dataclasses.replace(self._runs[name], comm_fault=fault)
-                self._fault_fns[k] = jax.jit(
+                self._fault_fns[k] = _jit_step(
                     make_paged_prefill_step(self.cfg, run, self.rules),
-                    donate_argnums=(1,))
+                    name)
             else:
                 self._prefill_fn(bucket)           # materialize the plan
                 run = dataclasses.replace(self._runs[f"prefill@{bucket}"],
                                           comm_fault=fault)
-                self._fault_fns[k] = jax.jit(
+                self._fault_fns[k] = _jit_step(
                     make_prefill_cache_step(self.cfg, run, self.rules),
-                    donate_argnums=(1,))
+                    f"prefill@{bucket}")
         return self._fault_fns[k]
 
     def _stall_applies(self, island: str, kind: str) -> bool:
@@ -652,9 +696,9 @@ class ServingEngine:
                 base, plans=plans, overrides=ov)
             self._runs[name] = dataclasses.replace(
                 self.base_run, island_overrides=ov)
-        self._decode_fn = jax.jit(
+        self._decode_fn = _jit_step(
             make_serve_step(self.cfg, self._runs["decode"], self.rules),
-            donate_argnums=(1,))
+            "decode")
         self._prefill_fns.clear()
         self._fault_fns.clear()
 
@@ -998,78 +1042,89 @@ class ServingEngine:
                               self.cache["blocks"])
         self.cache = self._recommit_cache({**self.cache, "blocks": blocks})
 
-    def _prefill_chunk_step(self) -> None:
+    def _prefill_chunk_step(self, t: StepTimer) -> None:
         """Run the job's next chunk; the live cache's block-table rows stay
         at the -1 sentinel until ``_finish_prefill_job`` commits, so decode
         ticks interleaved between chunks cannot touch half-built pages."""
         job = self._job
         c = job.next_chunk
         c0 = c * job.chunk_len
-        fn = self._paged_prefill_fn(job.bucket)
-        if self._current_fault is not None:
-            fn = self._faulted_fn(("paged", job.bucket), self._current_fault)
-        logits, self.cache = fn(
-            self.params, self.cache,
-            jnp.asarray(job.tokens[:, c0:c0 + job.chunk_len]),
-            jnp.asarray(job.group_bt), jnp.asarray(job.lens),
-            jnp.asarray(c0, jnp.int32), jnp.asarray(job.write_from))
-        finite = self._finite_rows(logits)
-        first = self._greedy(logits)
-        for row, r in enumerate(job.reqs):
-            if r is not None and job.logit_chunk[row] == c:
-                if not finite[row]:
-                    job.poisoned[row] = True
-                job.first_token[row] = int(first[row])
-        self.events.append(
-            ("prefill_chunk", self.step_no,
-             tuple(r.rid for r in job.reqs if r is not None),
-             c, job.n_chunks))
-        job.next_chunk += 1
+        with t.phase("engine.prefill.inputs"):
+            fn = self._paged_prefill_fn(job.bucket)
+            if self._current_fault is not None:
+                fn = self._faulted_fn(("paged", job.bucket),
+                                      self._current_fault)
+            args = (jnp.asarray(job.tokens[:, c0:c0 + job.chunk_len]),
+                    jnp.asarray(job.group_bt), jnp.asarray(job.lens),
+                    jnp.asarray(c0, jnp.int32), jnp.asarray(job.write_from))
+            real = [r is not None for r in job.reqs]
+            self.prefill_tokens += int(np.clip(job.lens[real] - c0, 0,
+                                               job.chunk_len).sum())
+            self.prefill_slot_tokens += job.chunk_len * len(job.reqs)
+        with t.phase("engine.dispatch"):
+            logits, self.cache = fn(self.params, self.cache, *args)
+        with t.phase("engine.sample"):
+            finite = self._finite_rows(logits)
+            first = self._greedy(logits)
+        with t.phase("engine.bookkeeping"):
+            for row, r in enumerate(job.reqs):
+                if r is not None and job.logit_chunk[row] == c:
+                    if not finite[row]:
+                        job.poisoned[row] = True
+                    job.first_token[row] = int(first[row])
+            self.events.append(
+                ("prefill_chunk", self.step_no,
+                 tuple(r.rid for r in job.reqs if r is not None),
+                 c, job.n_chunks))
+            job.next_chunk += 1
         if job.next_chunk > job.end_chunk:
-            self._finish_prefill_job()
+            self._finish_prefill_job(t)
 
-    def _finish_prefill_job(self) -> None:
+    def _finish_prefill_job(self, t: StepTimer) -> None:
         """Last chunk done: commit block-table rows + positions into the
         live cache, open the slots, register prompts for prefix sharing."""
         job, geom = self._job, self.geom
         self._job = None
         # poisoned rows never commit: block-table rows stay -1, their pages
         # go back to the pool, and the request retries or quarantines
-        for i in [i for i, r in enumerate(job.reqs)
-                  if r is not None and job.poisoned[i]]:
-            self.allocator.release(job.pages[i])
-            self._poisoned(job.reqs[i], "prefill_nonfinite")
+        with t.phase("engine.bookkeeping"):
+            for i in [i for i, r in enumerate(job.reqs)
+                      if r is not None and job.poisoned[i]]:
+                self.allocator.release(job.pages[i])
+                self._poisoned(job.reqs[i], "prefill_nonfinite")
         rows = [i for i, r in enumerate(job.reqs)
                 if r is not None and not job.poisoned[i]]
         if not rows:
             return
-        idx = np.asarray([job.slot_ids[i] for i in rows])
-        for i in rows:
-            self._bt_host[job.slot_ids[i]] = job.group_bt[i]
-        self._commit_leaf("block_tables",
-                          self.cache["block_tables"]
-                          .at[idx].set(jnp.asarray(job.group_bt[rows])))
-        self._commit_leaf("pos", self.cache["pos"]
-                          .at[idx].set(jnp.asarray(job.lens[rows])))
-        for i in rows:
-            r, slot = job.reqs[i], job.slot_ids[i]
-            self._slot_pages[slot] = job.pages[i]
-            if self._share_ok:
-                part = geom.slot_partition(slot, self.serve.max_batch)
-                self.prefix.register(
-                    part, r.prompt,
-                    job.pages[i][:geom.pages_for(len(r.prompt))],
-                    ("chunk", job.chunk_len))
-            tok = job.first_token[i]
-            self.slots[slot] = _Slot(
-                rid=r.rid, last_token=tok, remaining=r.max_new_tokens - 1,
-                tokens=[tok], admitted_step=job.started_step,
-                bucket=job.bucket, prompt_len=len(r.prompt))
-            self.tokens_generated += 1
-            self.events.append(("admit", self.step_no, r.rid, slot,
-                                job.bucket, self._mem_metrics()))
-            if self.slots[slot].remaining == 0:
-                self._retire(slot)
+        with t.phase("engine.prefill.scatter"):
+            idx = np.asarray([job.slot_ids[i] for i in rows])
+            for i in rows:
+                self._bt_host[job.slot_ids[i]] = job.group_bt[i]
+            self._commit_leaf("block_tables",
+                              self.cache["block_tables"]
+                              .at[idx].set(jnp.asarray(job.group_bt[rows])))
+            self._commit_leaf("pos", self.cache["pos"]
+                              .at[idx].set(jnp.asarray(job.lens[rows])))
+        with t.phase("engine.bookkeeping"):
+            for i in rows:
+                r, slot = job.reqs[i], job.slot_ids[i]
+                self._slot_pages[slot] = job.pages[i]
+                if self._share_ok:
+                    part = geom.slot_partition(slot, self.serve.max_batch)
+                    self.prefix.register(
+                        part, r.prompt,
+                        job.pages[i][:geom.pages_for(len(r.prompt))],
+                        ("chunk", job.chunk_len))
+                tok = job.first_token[i]
+                self.slots[slot] = _Slot(
+                    rid=r.rid, last_token=tok, remaining=r.max_new_tokens - 1,
+                    tokens=[tok], admitted_step=job.started_step,
+                    bucket=job.bucket, prompt_len=len(r.prompt))
+                self.tokens_generated += 1
+                self.events.append(("admit", self.step_no, r.rid, slot,
+                                    job.bucket, self._mem_metrics()))
+                if self.slots[slot].remaining == 0:
+                    self._retire(slot)
 
     def _prefill_inputs(self, bucket: int, prompts) -> tuple:
         """(tokens, lens) of one prefill group, right-padded to ``bucket``;
@@ -1101,16 +1156,23 @@ class ServingEngine:
         return np.asarray(logits[:len(prompts), -1, :self.cfg.vocab_size],
                           np.float32)
 
-    def _prefill(self, bucket: int, reqs: list[Request],
+    def _prefill(self, t: StepTimer, bucket: int, reqs: list[Request],
                  slot_ids: list[int]) -> None:
-        fn = self._prefill_fn(bucket)
-        if self._current_fault is not None:
-            fn = self._faulted_fn(("prefill", bucket), self._current_fault)
-        tokens, lens = self._prefill_inputs(bucket, [r.prompt for r in reqs])
-        gcache = self._sharded_zeros(self._prefill_tmpls[bucket])
-        logits, gcache = fn(self.params, gcache, tokens, lens)
-        finite = self._finite_rows(logits)
-        first = self._greedy(logits)
+        with t.phase("engine.prefill.inputs"):
+            fn = self._prefill_fn(bucket)
+            if self._current_fault is not None:
+                fn = self._faulted_fn(("prefill", bucket),
+                                      self._current_fault)
+            tokens, lens = self._prefill_inputs(bucket,
+                                                [r.prompt for r in reqs])
+            gcache = self._sharded_zeros(self._prefill_tmpls[bucket])
+            self.prefill_tokens += sum(len(r.prompt) for r in reqs)
+            self.prefill_slot_tokens += bucket * self.serve.prefill_batch
+        with t.phase("engine.dispatch"):
+            logits, gcache = fn(self.params, gcache, tokens, lens)
+        with t.phase("engine.sample"):
+            finite = self._finite_rows(logits)
+            first = self._greedy(logits)
         # only finite rows scatter into the live cache and open slots —
         # poisoned rows retry or quarantine, and because every slot's cache
         # row is independent the survivors' tokens are unaffected
@@ -1125,22 +1187,24 @@ class ServingEngine:
                     return dst.at[idx].set(src[rows])
                 return dst.at[:, idx].set(src[:, rows])
 
-            self.cache = self._recommit_cache(
-                jax.tree.map(scatter, self.cache, gcache))
-        for i in ok:
-            r, slot = reqs[i], slot_ids[i]
-            self.slots[slot] = _Slot(
-                rid=r.rid, last_token=int(first[i]),
-                remaining=r.max_new_tokens - 1,
-                tokens=[int(first[i])], admitted_step=self.step_no,
-                bucket=bucket, prompt_len=len(r.prompt))
-            self.events.append(("admit", self.step_no, r.rid, slot, bucket,
-                                self._mem_metrics()))
-            self.tokens_generated += 1
-            if self.slots[slot].remaining == 0:
-                self._retire(slot)
-        for i in bad:
-            self._poisoned(reqs[i], "prefill_nonfinite")
+            with t.phase("engine.prefill.scatter"):
+                self.cache = self._recommit_cache(
+                    jax.tree.map(scatter, self.cache, gcache))
+        with t.phase("engine.bookkeeping"):
+            for i in ok:
+                r, slot = reqs[i], slot_ids[i]
+                self.slots[slot] = _Slot(
+                    rid=r.rid, last_token=int(first[i]),
+                    remaining=r.max_new_tokens - 1,
+                    tokens=[int(first[i])], admitted_step=self.step_no,
+                    bucket=bucket, prompt_len=len(r.prompt))
+                self.events.append(("admit", self.step_no, r.rid, slot,
+                                    bucket, self._mem_metrics()))
+                self.tokens_generated += 1
+                if self.slots[slot].remaining == 0:
+                    self._retire(slot)
+            for i in bad:
+                self._poisoned(reqs[i], "prefill_nonfinite")
 
     def _retire(self, slot: int) -> None:
         s = self.slots[slot]
@@ -1162,92 +1226,119 @@ class ServingEngine:
         self.events.append(("retire", self.step_no, s.rid, slot,
                             self._mem_metrics()))
 
-    def _decode_tick(self) -> None:
-        tokens = np.zeros((self.serve.max_batch, 1), np.int32)
-        for i, s in enumerate(self.slots):
-            if s is not None:
-                tokens[i, 0] = s.last_token
-        fn = self._decode_fn
-        if self._current_fault is not None:
-            fn = self._faulted_fn(("decode", 0), self._current_fault)
-        logits, self.cache = fn(self.params, self.cache,
-                                jnp.asarray(tokens))
-        finite = self._finite_rows(logits)
-        nxt = self._greedy(logits)
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            if not finite[i]:
-                # mid-decode poison: the slot's cache row may hold corrupted
-                # K/V, so quarantine directly — no retry, since replaying the
-                # partial generation cannot be trusted from poisoned state
-                self.quarantined[s.rid] = {"prompt_len": s.prompt_len,
-                                           "step": self.step_no,
-                                           "reason": "decode_nonfinite"}
-                self.events.append(("quarantine", self.step_no, s.rid))
-                self._evict_slot(i)
-                continue
-            s.last_token = int(nxt[i])
-            s.tokens.append(s.last_token)
-            s.remaining -= 1
-            self.tokens_generated += 1
-            if s.remaining == 0:
-                self._retire(i)
+    def _decode_tick(self, t: StepTimer) -> None:
+        with t.phase("engine.decode.inputs"):
+            tokens = np.zeros((self.serve.max_batch, 1), np.int32)
+            for i, s in enumerate(self.slots):
+                if s is not None:
+                    tokens[i, 0] = s.last_token
+            fn = self._decode_fn
+            if self._current_fault is not None:
+                fn = self._faulted_fn(("decode", 0), self._current_fault)
+            tokens = jnp.asarray(tokens)
+        with t.phase("engine.dispatch"):
+            logits, self.cache = fn(self.params, self.cache, tokens)
+        with t.phase("engine.sample"):
+            finite = self._finite_rows(logits)
+            nxt = self._greedy(logits)
+        with t.phase("engine.bookkeeping"):
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                if not finite[i]:
+                    # mid-decode poison: the slot's cache row may hold
+                    # corrupted K/V, so quarantine directly — no retry,
+                    # since replaying the partial generation cannot be
+                    # trusted from poisoned state
+                    self.quarantined[s.rid] = {"prompt_len": s.prompt_len,
+                                               "step": self.step_no,
+                                               "reason": "decode_nonfinite"}
+                    self.events.append(("quarantine", self.step_no, s.rid))
+                    self._evict_slot(i)
+                    continue
+                s.last_token = int(nxt[i])
+                s.tokens.append(s.last_token)
+                s.remaining -= 1
+                self.tokens_generated += 1
+                if s.remaining == 0:
+                    self._retire(i)
 
-    def step(self) -> str | None:
-        """One engine step: a bucket prefill when admission is possible,
-        else a decode tick over the pool. Returns the step kind, or None
-        when fully idle.
+    def _schedule(self) -> tuple[str | None, Any]:
+        """(kind, group) of the step about to run: ``("prefill", group)``
+        (a paged group is None while a chunked job is in flight),
+        ``("decode", None)``, ``("idle", None)`` while every queued request
+        backs off after a retry, or ``(None, None)`` with nothing pending.
 
         Paged mode runs ONE prefill chunk per prefill step and alternates
         with decode ticks while a job is in flight (chunk, decode, chunk,
         ...), so decode latency is bounded by a chunk — the whole point of
         chunked prefill. Pool exhaustion shows up here as "no group" with a
         non-empty queue: the step decodes instead, draining pages."""
-        self._fire_comm_faults()
-        self._expire_deadlines()
         active = any(s is not None for s in self.slots)
-        if self.paged:
-            group = self._next_group_paged()
-            if group is None and self._job is None and not active:
-                if self.queue and not self.draining:
-                    if any(self._not_before.get(r.rid, 0) <= self.step_no
-                           for r in self.queue):
-                        raise RuntimeError(
-                            "paged admission deadlock: queue non-empty but "
-                            "no slots/pages can ever free (pool undersized?)")
-                    # every queued request is backing off after a retry —
-                    # burn an idle step so the gates can open
-                    return self._record_step("idle", 0.0)
-                return None
-            with StepTimer() as t:
-                if group is not None:
-                    self._start_prefill_job(*group)
-                    self._prefill_chunk_step()
-                    kind = "prefill"
-                elif self._job is not None and not (
-                        active and self.step_kinds
-                        and self.step_kinds[-1] == "prefill"):
-                    self._prefill_chunk_step()
-                    kind = "prefill"
-                else:
-                    self._decode_tick()
-                    kind = "decode"
-            return self._record_step(kind, t.dt)
-        group = self._next_group()
-        if group is None and not active:
-            if self.queue and not self.draining:
-                # all queued requests are in retry backoff: idle-tick
-                return self._record_step("idle", 0.0)
-            return None
-        with StepTimer() as t:
+        if not self.paged:
+            group = self._next_group()
             if group is not None:
-                self._prefill(*group)
-                kind = "prefill"
-            else:
-                self._decode_tick()
-                kind = "decode"
-        return self._record_step(kind, t.dt)
+                return "prefill", group
+            if active:
+                return "decode", None
+            return ("idle" if self.queue and not self.draining
+                    else None), None
+        group = self._next_group_paged()
+        if group is not None:
+            return "prefill", group
+        if self._job is not None and not (
+                active and self.step_kinds
+                and self.step_kinds[-1] == "prefill"):
+            return "prefill", None
+        if self._job is not None or active:
+            return "decode", None
+        if self.queue and not self.draining:
+            if any(self._not_before.get(r.rid, 0) <= self.step_no
+                   for r in self.queue):
+                raise RuntimeError(
+                    "paged admission deadlock: queue non-empty but "
+                    "no slots/pages can ever free (pool undersized?)")
+            return "idle", None
+        return None, None
+
+    def step(self) -> str | None:
+        """One engine step: a bucket prefill when admission is possible,
+        else a decode tick over the pool. Returns the step kind, or None
+        when fully idle.
+
+        The step is timed in phases (``StepTimer.phase``) that tile it, on
+        both cache layouts: ``engine.schedule``, ``engine.prefill.inputs``,
+        ``engine.decode.inputs``, ``engine.dispatch`` (the jitted call up to
+        its return), ``engine.sample`` (where the host waits on the
+        device), ``engine.prefill.scatter`` and ``engine.bookkeeping``.
+        ``last_phases`` holds the split of the step just taken,
+        ``stats()["phase_s"]`` the sums; a profiler trace shows the same
+        names inside an ``engine.step`` span."""
+        t = StepTimer()
+        with TraceAnnotation("engine.step"), t:
+            with t.phase("engine.schedule"):
+                self._fire_comm_faults()
+                self._expire_deadlines()
+                kind, group = self._schedule()
+            if kind is None:
+                return None
+            if kind == "prefill" and self.paged:
+                if group is not None:
+                    with t.phase("engine.prefill.inputs"):
+                        self._start_prefill_job(*group)
+                self._prefill_chunk_step(t)
+            elif kind == "prefill":
+                self._prefill(t, *group)
+            elif kind == "decode":
+                self._decode_tick(t)
+            with t.phase("engine.bookkeeping"):
+                # an idle tick records no time: nothing ran
+                self._record_step(kind,
+                                  t.elapsed() if kind != "idle" else 0.0)
+        self.last_phases = t.phases
+        for name, sec in t.phases.items():
+            self.phase_s[name] = self.phase_s.get(name, 0.0) + sec
+        return kind
 
     def _record_step(self, kind: str, dt: float) -> str:
         """Shared step accounting: injected fault delay folds into the
@@ -1439,6 +1530,7 @@ class ServingEngine:
             "tokens_generated": self.tokens_generated,
             "wall_s": total,
             "tokens_per_s": self.tokens_generated / total if total else 0.0,
+            "phase_s": dict(self.phase_s),
             "straggler_events": len(self.watchdog.events),
             "compiled_buckets": self.compiled_buckets,
             "cache": self.cache_stats(),

@@ -10,12 +10,18 @@ a replica whose step-time EMA exceeds ``factor`` x the median EMA of its
 live peers is a *fleet* straggler even if its own per-step deadline never
 fires (a uniformly-slow replica looks healthy to itself). The fleet router
 steals queued requests from flagged replicas.
+
+``StepTimer`` is the one step clock: the training driver and the fleet time
+whole steps with it, and the serving engine also splits each step into
+named phases (``StepTimer.phase``), which a profiler trace shows as spans.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -101,8 +107,17 @@ class FleetWatchdog:
 
 
 class StepTimer:
+    """Wall time of one step (``dt``) and of the named phases inside it.
+
+    ``phase(name)`` adds the seconds spent inside it to ``phases[name]`` and
+    opens a profiler ``TraceAnnotation`` of the same name, so a device trace
+    carries the same split on its host line. With no profiler running the
+    annotation does nothing but the call; nothing turns it on or off.
+    """
+
     def __init__(self):
         self.t0 = None
+        self.phases: dict[str, float] = {}
 
     def __enter__(self):
         self.t0 = time.perf_counter()
@@ -110,3 +125,27 @@ class StepTimer:
 
     def __exit__(self, *a):
         self.dt = time.perf_counter() - self.t0
+
+    def elapsed(self) -> float:
+        """Seconds since the step started, while it runs."""
+        return time.perf_counter() - self.t0
+
+    def phase(self, name: str) -> "_Phase":
+        return _Phase(self.phases, name)
+
+
+class _Phase:
+    __slots__ = ("phases", "name", "span", "t0")
+
+    def __init__(self, phases: dict, name: str):
+        self.phases, self.name = phases, name
+        self.span = TraceAnnotation(name)
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *a):
+        dt = time.perf_counter() - self.t0
+        self.span.__exit__(*a)
+        self.phases[self.name] = self.phases.get(self.name, 0.0) + dt
