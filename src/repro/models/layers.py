@@ -94,13 +94,8 @@ def apply_rope(x, positions, theta: float):
 # Attention
 # ---------------------------------------------------------------------------
 
-def _full_attention(q, k, v, *, causal, window, q_offset=0, kv_len=None,
-                    scale=None):
-    """q: (B,Hq,Sq,hd); k,v: (B,Hkv,Skv,hd). fp32 softmax, GQA grouped.
-
-    ``kv_len`` (valid cache prefix) may be a scalar or a per-slot ``(B,)``
-    vector — the continuous-batching decode pool holds sequences at
-    different positions in one batch."""
+def _full_attention(q, k, v, *, causal, window, scale=None):
+    """q: (B,Hq,Sq,hd); k,v: (B,Hkv,Skv,hd). fp32 softmax, GQA grouped."""
     b, hq, sq, hd = q.shape
     hkv, skv = k.shape[1], k.shape[2]
     g = hq // hkv
@@ -108,20 +103,14 @@ def _full_attention(q, k, v, *, causal, window, q_offset=0, kv_len=None,
     qg = q.reshape(b, hkv, g, sq, hd)
     s = jnp.einsum("bkgqd,bksd->bkgqs", qg, k,
                    preferred_element_type=jnp.float32) * scale
-    qi = q_offset + jnp.arange(sq)[:, None]
+    qi = jnp.arange(sq)[:, None]
     ki = jnp.arange(skv)[None, :]
     keep = jnp.ones((sq, skv), bool)
     if causal:
         keep &= ki <= qi
     if window is not None:
         keep &= ki > qi - window
-    if kv_len is not None:                      # decode: valid cache prefix
-        if jnp.ndim(kv_len):                    # per-slot (B,) prefix
-            keep = keep[None] & (ki[None] < kv_len[:, None, None])
-        else:
-            keep = keep & (ki < kv_len)
-    mask = keep if keep.ndim == 2 else keep[:, None, None]
-    s = jnp.where(mask, s, NEG_INF)
+    s = jnp.where(keep, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgqs,bksd->bkgqd", p, v.astype(jnp.float32))
     return o.reshape(b, hq, sq, hd).astype(q.dtype)
@@ -342,23 +331,30 @@ def attention_block(p, x, cfg: ArchConfig, run: RunConfig,
     return out
 
 
-def _cache_write(cache, new, pos):
-    """Write a one-token K/V block into the cache's seq dim at ``pos``.
+def _write_rows(cache, row, pos):
+    """Write one decode step's row per slot into a stacked cache leaf.
 
-    cache: (B, H, S, hd); new: (B, H, 1, hd). Scalar ``pos`` is the classic
-    lockstep decode (dynamic_update_slice); a ``(B,)`` vector writes each
-    slot at its own position via a one-hot select — the continuous-batching
-    pool's slots sit at different depths. Out-of-range vector positions
-    write nothing (the engine parks inactive slots past their cache).
-    Rank-3 ``(B, H, S)`` caches (the int8 mode's per-token scale planes,
-    seq still dim 2) take the same write."""
+    cache: (n_periods, B, H, S, hd), or (n_periods, B, H, S) for the int8
+    mode's per-token scale planes; row: the same with S = 1. Scalar ``pos``
+    is the classic lockstep decode (dynamic_update_slice); a ``(B,)``
+    vector scatters each slot's row at its own position — the
+    continuous-batching pool's slots sit at different depths — and a
+    position past the cache writes nothing (``mode="drop"``: the engine
+    parks inactive slots there). Either is an in-place update of a donated
+    cache."""
+    row = row.astype(cache.dtype)
     if jnp.ndim(pos) == 0:
         return lax.dynamic_update_slice(
-            cache, new.astype(cache.dtype),
-            (0, 0, pos) + (0,) * (cache.ndim - 3))
-    oh = jnp.arange(cache.shape[2])[None, :] == pos[:, None]       # (B, S)
-    mask = oh[:, None, :, None] if cache.ndim == 4 else oh[:, None, :]
-    return jnp.where(mask, new.astype(cache.dtype), cache)
+            cache, row, (0, 0, 0, pos) + (0,) * (cache.ndim - 4))
+    # index every (period, slot, head): the write's window is then the
+    # minor hd dim alone, which the TPU scatters into the slab's own layout
+    # (a window over the heads too makes XLA relayout the whole slab)
+    n_p, b, h = cache.shape[:3]
+    return cache.at[jnp.arange(n_p)[:, None, None],
+                    jnp.arange(b)[None, :, None],
+                    jnp.arange(h)[None, None, :],
+                    pos[None, :, None]].set(row[:, :, :, 0], mode="drop",
+                                            unique_indices=True)
 
 
 # int8 KV cache (ServeConfig.kv_dtype="int8"): K/V stored as int8 with ONE
@@ -385,129 +381,146 @@ def _kv_dequantize(q, scale, dtype):
 
 def decode_island(cfg: ArchConfig, run: RunConfig,
                   rules: ShardingRules | None, b: int, s_max: int, *,
-                  long_ctx: bool, pos, kv_len, window,
+                  long_ctx: bool, pos, window,
                   quant: bool = False) -> Island:
-    """One-token decode over the sequence-sharded KV cache: shard-local slot
-    write + flash-decode logsumexp merge over the tp axis (DESIGN §4). The
-    cache write happens INSIDE the island — a dynamic_update_slice on a
-    seq-sharded array at the jit level would force XLA to all-gather the
-    whole cache (GBs per token). ``pos``/``kv_len`` may be scalars (lockstep
-    decode) or per-slot ``(B,)`` vectors (the serving engine's mixed pool).
-    ``quant``: the cache is int8 with per-(token, head) f32 scale planes
-    (``cache_ks``/``cache_vs`` inputs) — the new token is quantized before
-    its write and the whole cache dequantized for the mix."""
+    """One-token decode attention over the read-only KV cache plus the
+    step's new row. The scores over the old positions ``ki < pos`` (window
+    applied) and the new row's ``q·k_new`` meet in one f32 softmax,
+    ``o = p_old @ V_old + p_new · v_new`` — the same as writing the row
+    first and attending over ``ki < pos + 1``. The row counts where it will
+    be written (``pos < s_max``; over the sequence-sharded cache, on the
+    shard that holds ``pos``), so a parked slot reads the cache as it
+    stays; ``decode_write_island`` writes it after the layer scan.
+    Sharded: flash-decode logsumexp merge over the tp axis (DESIGN §4).
+    ``pos`` may be a scalar (lockstep decode) or a per-slot ``(B,)`` vector
+    (the serving engine's mixed pool). ``quant``: the cache is int8 with
+    per-(token, head) f32 scale planes (``cache_ks``/``cache_vs`` inputs),
+    dequantized for the mix; the caller passes the new row as the cache
+    will hold it."""
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     vec = jnp.ndim(pos) > 0
 
-    def _mix(q, k_, v_, offset, s_loc, axis, pos_in):
-        """Local partial attention + logsumexp merge over the axis.
+    def _mix(q, k_, v_, k_new, v_new, offset, pos_in, axis=None):
+        """Local partial attention, merged over ``axis`` when sharded.
         ``pos_in`` scalar or (B_loc,) — the shard-local slice of pos."""
-        g = hq // hkv
+        g, s_loc = hq // hkv, k_.shape[2]
         qg = q.reshape(q.shape[0], hkv, g, 1, hd)
-        s_ = jnp.einsum("bkgqd,bksd->bkgqs", qg, k_,
-                        preferred_element_type=jnp.float32) * hd ** -0.5
-        ki = offset + jnp.arange(s_loc)[None, None, None, None, :]
-        kvl = pos_in[:, None, None, None, None] + 1 if vec else kv_len
-        keep = ki < kvl
+        s_old = jnp.einsum("bkgqd,bksd->bkgqs", qg, k_,
+                           preferred_element_type=jnp.float32) * hd ** -0.5
+        s_new = jnp.einsum("bkgqd,bkqd->bkgq", qg, k_new,
+                           preferred_element_type=jnp.float32) * hd ** -0.5
+        p4 = jnp.reshape(pos_in, (-1, 1, 1, 1) if vec else ())  # (b,1,1,1)
+        ki = offset + jnp.arange(s_loc)
+        keep = ki < p4[..., None]
         if window is not None:
-            keep &= ki > (kvl - 1) - window
-        s_ = jnp.where(keep, s_, NEG_INF)
-        m_loc = s_.max(axis=-1)                                # (b,k,g,1)
-        m_glob = lax.pmax(m_loc, axis)
-        p_ = jnp.exp(s_ - m_glob[..., None])
-        l_loc = p_.sum(axis=-1)
-        o_loc = jnp.einsum("bkgqs,bksd->bkgqd", p_, v_.astype(jnp.float32))
-        l_glob = lax.psum(l_loc, axis)
-        o_glob = lax.psum(o_loc, axis)
-        o = o_glob / jnp.maximum(l_glob, 1e-30)[..., None]
+            keep &= ki > p4[..., None] - window
+        s_old = jnp.where(keep, s_old, NEG_INF)
+        s_new = jnp.where((p4 >= offset) & (p4 < offset + s_loc), s_new,
+                          NEG_INF)
+        m = jnp.maximum(s_old.max(axis=-1), s_new)               # (b,k,g,1)
+        if axis is not None:
+            m = lax.pmax(m, axis)
+        p_old = jnp.exp(s_old - m[..., None])
+        p_new = jnp.exp(s_new - m)
+        l_ = p_old.sum(axis=-1) + p_new
+        o = jnp.einsum("bkgqs,bksd->bkgqd", p_old, v_.astype(jnp.float32)) \
+            + p_new[..., None] * v_new.astype(jnp.float32)[:, :, None]
+        if axis is not None:
+            l_, o = lax.psum(l_, axis), lax.psum(o, axis)
+        o = o / jnp.maximum(l_, 1e-30)[..., None]
         return o.reshape(q.shape[0], hq, 1, hd).astype(q.dtype)
+
+    def _cached(q, cache_k, cache_v, kw):
+        """The cache's K/V as the mix reads them."""
+        if not quant:
+            return cache_k, cache_v
+        return (_kv_dequantize(cache_k, kw["cache_ks"], q.dtype),
+                _kv_dequantize(cache_v, kw["cache_vs"], q.dtype))
 
     # Per-slot (vector) pos is an ISLAND INPUT sharded like the batch — a
     # closure capture would hand every shard the global-batch vector while
     # its arrays are dp-local. The scalar (lockstep) form keeps the closure.
     def reference(q, cache_k, cache_v, k_new, v_new, **kw):
-        p_ = kw.get("pos", pos)
-        if quant:
-            qk, sk = _kv_quantize(k_new)
-            qv, sv = _kv_quantize(v_new)
-            ck = _cache_write(cache_k, qk, p_)
-            cv = _cache_write(cache_v, qv, p_)
-            ks = _cache_write(kw["cache_ks"], sk, p_)
-            vs = _cache_write(kw["cache_vs"], sv, p_)
-            o = _full_attention(q, _kv_dequantize(ck, ks, q.dtype),
-                                _kv_dequantize(cv, vs, q.dtype),
-                                causal=False, window=window, q_offset=0,
-                                kv_len=p_ + 1 if vec else kv_len)
-            return o, ck, cv, ks, vs
-        ck = _cache_write(cache_k, k_new, p_)
-        cv = _cache_write(cache_v, v_new, p_)
-        o = _full_attention(q, ck, cv, causal=False, window=window,
-                            q_offset=0,
-                            kv_len=p_ + 1 if vec else kv_len)
-        return o, ck, cv
+        k_, v_ = _cached(q, cache_k, cache_v, kw)
+        return _mix(q, k_, v_, k_new, v_new, 0, kw.get("pos", pos))
 
     if rules is None:
         return Island("decode_attn", run=run, reference=reference)
     tp = rules.tp
     axis = (tuple(run.dp_axes) + (tp,)) if long_ctx else tp
     cache_spec = rules.kv_cache(hkv, b, long_ctx=long_ctx)
-    scale_spec = P(*cache_spec[:3])
     bspec = None if long_ctx else rules.dim(b, rules.dp)
     qspec = P(bspec, None, None, None)
 
     def body(ctx, q, cache_k, cache_v, k_new, v_new, **kw):
-        p_ = kw.get("pos", pos)
-        ax_idx = lax.axis_index(axis)
-        s_loc = cache_k.shape[2]
-        offset = ax_idx * s_loc
-        # shard-local cache update (one-sided, pre-allocated slot — the
-        # PK §3.1.4 principle applied to the KV cache)
-        local_pos = p_ - offset
-        hit = (local_pos >= 0) & (local_pos < s_loc)
-        lp = jnp.clip(local_pos, 0, s_loc - 1)
-
-        if vec:
-            def upd(c, n):
-                oh = (jnp.arange(s_loc)[None, :] == lp[:, None]) \
-                    & hit[:, None]                             # (B, s_loc)
-                mask = oh[:, None, :, None] if c.ndim == 4 else oh[:, None, :]
-                return jnp.where(mask, n.astype(c.dtype), c)
-        else:
-            def upd(c, n):
-                new = lax.dynamic_update_slice(
-                    c, n.astype(c.dtype), (0, 0, lp) + (0,) * (c.ndim - 3))
-                return lax.cond(hit, lambda: new, lambda: c)
-
-        if quant:
-            qk, sk = _kv_quantize(k_new)
-            qv, sv = _kv_quantize(v_new)
-            ck, cv = upd(cache_k, qk), upd(cache_v, qv)
-            ks, vs = upd(kw["cache_ks"], sk), upd(kw["cache_vs"], sv)
-            k_ = _kv_dequantize(ck, ks, q.dtype)
-            v_ = _kv_dequantize(cv, vs, q.dtype)
-            return (_mix(q, k_, v_, offset, s_loc, axis, p_),
-                    ck, cv, ks, vs)
-        k_ = upd(cache_k, k_new)
-        v_ = upd(cache_v, v_new)
-        return (_mix(q, k_, v_, offset, s_loc, axis, p_), k_, v_)
+        k_, v_ = _cached(q, cache_k, cache_v, kw)
+        offset = lax.axis_index(axis) * cache_k.shape[2]
+        return _mix(q, k_, v_, k_new, v_new, offset, kw.get("pos", pos),
+                    axis)
 
     inputs = {"q": qspec, "cache_k": cache_spec, "cache_v": cache_spec,
               "k_new": qspec, "v_new": qspec}
     if quant:
-        inputs["cache_ks"] = scale_spec
-        inputs["cache_vs"] = scale_spec
+        inputs["cache_ks"] = inputs["cache_vs"] = P(*cache_spec[:3])
     if vec:
         inputs["pos"] = P(bspec)
     return Island(
         "decode_attn", rules=rules, run=run, axis=tp, fallback_axes=axis,
-        inputs=inputs,
-        out_specs=((qspec, cache_spec, cache_spec, scale_spec, scale_spec)
-                   if quant else (qspec, cache_spec, cache_spec)),
+        inputs=inputs, out_specs=qspec,
         body=body, reference=reference,
         enable=run.decode_seq_shard,
         divisible=((s_max, axis),),
         comm=Comm("psum", backend="bulk", n_chunks=1,
                   payload_bytes=2 * b * hq * hd * 4))
+
+
+def decode_write_island(cfg: ArchConfig, run: RunConfig,
+                        rules: ShardingRules | None, b: int, s_max: int, *,
+                        long_ctx: bool, pos, quant: bool = False) -> Island:
+    """Write one decode step's K/V rows into one attention position's
+    stacked (n_periods, B, Hkv, S_max, hd) cache after the layer scan: one
+    in-place write per leaf (``_write_rows``). Over the sequence-sharded
+    cache each shard writes only the rows whose position it holds — a
+    jit-level scatter into the seq-sharded slab would make XLA gather it
+    (the PK §3.1.4 one-sided, pre-allocated slot applied to the KV cache).
+    Inputs ``cache`` and ``rows`` are {leaf: array} trees: ``k``/``v``, and
+    ``k_scale``/``v_scale`` with ``quant``. Same fallback predicate as
+    ``decode_island``."""
+    vec = jnp.ndim(pos) > 0
+
+    def reference(cache, rows, **kw):
+        p_ = kw.get("pos", pos)
+        return jax.tree.map(lambda c, r: _write_rows(c, r, p_), cache, rows)
+
+    if rules is None:
+        return Island("cache_write", run=run, reference=reference)
+    tp = rules.tp
+    axis = (tuple(run.dp_axes) + (tp,)) if long_ctx else tp
+    cache_spec = P(None, *rules.kv_cache(cfg.n_kv_heads, b,
+                                         long_ctx=long_ctx))
+    bspec = None if long_ctx else rules.dim(b, rules.dp)
+    row_spec = P(None, bspec, None, None, None)
+    leaf = {"k": cache_spec, "v": cache_spec}
+    row = {"k": row_spec, "v": row_spec}
+    if quant:
+        leaf["k_scale"] = leaf["v_scale"] = P(*cache_spec[:4])
+        row["k_scale"] = row["v_scale"] = P(None, bspec, None, None)
+
+    def body(ctx, cache, rows, **kw):
+        s_loc = cache["k"].shape[3]
+        lp = kw.get("pos", pos) - lax.axis_index(axis) * s_loc
+        # a row another shard holds goes past this shard's end: dropped
+        lp = jnp.where((lp >= 0) & (lp < s_loc), lp, s_loc)
+        return reference(cache, rows,
+                         pos=jnp.broadcast_to(lp, cache["k"].shape[1:2]))
+
+    inputs = {"cache": leaf, "rows": row}
+    if vec:
+        inputs["pos"] = P(bspec)
+    return Island(
+        "cache_write", rules=rules, run=run, axis=tp, fallback_axes=axis,
+        inputs=inputs, out_specs=leaf, body=body, reference=reference,
+        enable=run.decode_seq_shard, divisible=((s_max, axis),))
 
 
 def decode_attention(p, x, cache_k, cache_v, pos, cfg: ArchConfig,
@@ -516,16 +529,18 @@ def decode_attention(p, x, cache_k, cache_v, pos, cfg: ArchConfig,
                      v_scale=None):
     """One-token decode with KV cache.
 
-    x: (B, 1, d); cache_k/v: (B, Hkv, S_max, hd); pos: scalar current index.
-    Returns (out (B,1,d), new_k, new_v). Self-attention runs through the
+    x: (B, 1, d); cache_k/v: (B, Hkv, S_max, hd), read only; pos: scalar
+    current index or per-slot ``(B,)``. Returns (out (B,1,d), k_row, v_row):
+    the new K/V rows (B, Hkv, 1, hd) the caller writes at ``pos`` after the
+    layer scan (``decode_write_island``). Self-attention runs through the
     decode Island: with run.decode_seq_shard over the sharded cache — the SP
     serving path (DESIGN §4) — and otherwise, or on a single-device or
     indivisible mesh, through the island's dense reference.
 
     int8 mode: ``cache_k`` is int8 and ``k_scale``/``v_scale`` carry the
-    per-(token, head) f32 scale planes — the new token is quantized on
-    write, the cache dequantized on read, and the return grows to
-    (out, new_k, new_v, new_k_scale, new_v_scale).
+    per-(token, head) f32 scale planes — the new rows are quantized here and
+    enter the attention dequantized, as the cache will hold them; the
+    return grows to (out, k_row, v_row, k_scale_row, v_scale_row).
     """
     b, _, d = x.shape
     hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -543,32 +558,33 @@ def decode_attention(p, x, cache_k, cache_v, pos, cfg: ArchConfig,
             k_new = apply_rope(k_new, positions, cfg.rope_theta)
 
     if cross_kv is None:
+        if quant:
+            (k_row, ks_row), (v_row, vs_row) = (_kv_quantize(k_new),
+                                                _kv_quantize(v_new))
+            rows = (k_row, v_row, ks_row, vs_row)
+            k_new = _kv_dequantize(k_row, ks_row, q.dtype)
+            v_new = _kv_dequantize(v_row, vs_row, q.dtype)
+        else:
+            k_new = k_new.astype(cache_k.dtype)
+            v_new = v_new.astype(cache_v.dtype)
+            rows = (k_new, v_new)
         # the island's dense reference is the single-device cache path
         island = decode_island(cfg, run, rules, b, cache_k.shape[2],
-                               long_ctx=long_ctx, pos=pos, kv_len=pos + 1,
+                               long_ctx=long_ctx, pos=pos,
                                window=cfg.sliding_window, quant=quant)
         kw = {"pos": pos} if jnp.ndim(pos) else {}
         if quant:
             kw["cache_ks"], kw["cache_vs"] = k_scale, v_scale
-            o, cache_k, cache_v, k_scale, v_scale = island(
-                q=q, cache_k=cache_k, cache_v=cache_v, k_new=k_new,
-                v_new=v_new, **kw)
-        else:
-            o, cache_k, cache_v = island(q=q, cache_k=cache_k,
-                                         cache_v=cache_v, k_new=k_new,
-                                         v_new=v_new, **kw)
+        o = island(q=q, cache_k=cache_k, cache_v=cache_v, k_new=k_new,
+                   v_new=v_new, **kw)
     else:
         k_att, v_att = cross_kv
-        o = _full_attention(q, k_att, v_att, causal=False, window=None,
-                            q_offset=0, kv_len=k_att.shape[2])
+        o = _full_attention(q, k_att, v_att, causal=False, window=None)
+        rows = (None, None)
     with jax.named_scope("attn_out"):
         o = o.transpose(0, 2, 1, 3).reshape(b, 1, hq * hd)
         out = jnp.einsum("bsh,hd->bsd", o, p["wo"])
-    if cross_kv is not None:
-        return out, None, None
-    if quant:
-        return out, cache_k, cache_v, k_scale, v_scale
-    return out, cache_k, cache_v
+    return (out, *rows)
 
 
 def prefill_write_island(cfg: ArchConfig, run: RunConfig,
@@ -652,7 +668,7 @@ def prefill_attention_block(p, x, cache_k, cache_v, cfg: ArchConfig,
     m = B_loc·1 call (the whole point of per-bucket plans). Right-padding is
     safe: rows past a slot's real length are causal-masked garbage the
     caller discards, and the padded cache tail is never attended because
-    decode masks ``ki < kv_len`` with ``kv_len`` the slot's real position.
+    decode masks ``ki < pos`` with ``pos`` the slot's real length.
     Returns (out (B, L, d), new_cache_k, new_cache_v) — plus the updated
     ``k_scale``/``v_scale`` planes in int8 mode, where the prompt's K/V is
     quantized once and the prefill attends over the dequantized values so
@@ -1462,7 +1478,7 @@ def _forward_islands(cfg: ArchConfig, run: RunConfig,
             else:
                 islands.append(decode_island(
                     cfg, run, rules, b, seq, long_ctx=False, pos=0,
-                    kv_len=1, window=cfg.sliding_window))
+                    window=cfg.sliding_window))
     if any(sp.mlp == "dense" for sp in pattern):
         islands.append(mlp_island(cfg, run, rules, b, s))
     if any(sp.mlp == "moe" for sp in pattern):

@@ -416,11 +416,11 @@ def cache_template(cfg: ArchConfig, run: RunConfig, rules: ShardingRules | None,
 
 def _scan_cache(body, x, blocks, cache_blocks, cfg: ArchConfig,
                 run: RunConfig):
-    """Apply ``body(x, (period_params, period_cache)) -> (x, new_cache)``
-    over the periods, threading each period's cache slice through as scan
-    input and output (unrolled without ``run.scan_layers``). Named
-    ``cache_scan``: the copies and slab updates of that threading carry it
-    in their ``op_name`` metadata."""
+    """Apply ``body(x, (period_params, period_cache)) -> (x, out)`` over the
+    periods, each period's cache slice a scan input and ``out`` stacked as
+    the scan output (unrolled without ``run.scan_layers``). Named
+    ``cache_scan``: the cache slicing and the stacking carry it in their
+    ``op_name`` metadata."""
     with jax.named_scope("cache_scan"):
         if run.scan_layers:
             return lax.scan(body, x, (blocks, cache_blocks))
@@ -432,15 +432,39 @@ def _scan_cache(body, x, blocks, cache_blocks, cfg: ArchConfig,
         return x, jax.tree.map(lambda *xs: jnp.stack(xs), *new_list)
 
 
+def write_decode_rows(cache_blocks, rows, pos, cfg: ArchConfig,
+                      run: RunConfig, rules: ShardingRules | None, *,
+                      long_ctx: bool = False):
+    """Write the K/V rows one decode step's layer scan returned into the
+    stacked slabs, once, after the scan. ``rows``: {attention position:
+    {leaf: (n_periods, B, Hkv, 1[, hd])}}; each leaf takes one in-place
+    write at (period, slot, ``pos[slot]``) — a dynamic_update_slice for
+    scalar ``pos`` — through ``L.decode_write_island`` (scope
+    ``cache_write``). Returns the written entries of ``cache_blocks``."""
+    kw = {"pos": pos} if jnp.ndim(pos) else {}
+    out = {}
+    for name, r in rows.items():
+        c = cache_blocks[name]
+        island = L.decode_write_island(
+            cfg, run, rules, c["k"].shape[1], c["k"].shape[3],
+            long_ctx=long_ctx, pos=pos, quant="k_scale" in c)
+        out[name] = island(cache=c, rows=r, **kw)
+    return out
+
+
 def decode_step(params, cache, tokens, cfg: ArchConfig, run: RunConfig,
                 rules: ShardingRules | None, *, long_ctx: bool = False):
     """One decode step. tokens: (B, 1) int32. Returns (logits, new_cache).
 
-    Scans over periods with the per-period cache slices threaded as scan
-    inputs/outputs. RoPE position = cache["pos"]. A cache carrying
-    ``block_tables`` routes attention through the paged-pool islands
-    (``runtime/paging.py`` layout) — same step signature, so
-    ``make_serve_step`` and the engine's jit/donation story are unchanged.
+    Scans over periods with the per-period cache slices as scan inputs.
+    Slab caches: the scan returns only what changed — each attention
+    layer's new K/V rows, each Mamba layer's state — and the rows are
+    written into the slabs after the scan (``write_decode_rows``), so the
+    donated cache is updated in place. RoPE position = cache["pos"]. A
+    cache carrying ``block_tables`` routes attention through the paged-pool
+    islands (``runtime/paging.py`` layout), whose pools still thread
+    through the scan — same step signature, so ``make_serve_step`` and the
+    engine's jit/donation story are unchanged.
     """
     pos = cache["pos"]
     bt = cache.get("block_tables")
@@ -457,20 +481,18 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, run: RunConfig,
                 a = bp["attn"]
                 scales = ({"k_scale": cp["k_scale"], "v_scale": cp["v_scale"]}
                           if "k_scale" in cp else {})
-                if bt is not None:
-                    h, nk, nv, *ns = L.paged_decode_attention(
+                if bt is not None:          # the whole updated pools
+                    h, *kv = L.paged_decode_attention(
                         a, L.rms_norm(a["norm"], x, cfg.norm_eps), cp["k"],
                         cp["v"], bt, pos, cfg, run, rules, **scales)
-                else:
-                    h, nk, nv, *ns = L.decode_attention(
+                else:                       # the new rows
+                    h, *kv = L.decode_attention(
                         a, L.rms_norm(a["norm"], x, cfg.norm_eps), cp["k"],
                         cp["v"], pos, cfg, run, rules, long_ctx=long_ctx,
                         **scales)
                 x = x + h
-                nc = {"k": nk, "v": nv}
-                if scales:
-                    nc["k_scale"], nc["v_scale"] = ns
-                new_cache[f"pos{i}"] = nc
+                new_cache[f"pos{i}"] = dict(zip(
+                    ("k", "v", "k_scale", "v_scale"), kv))
             else:
                 mp = bp["mamba"]
                 h, (nh, nconv) = S.mamba_block(
@@ -491,6 +513,11 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, run: RunConfig,
 
     x, new_blocks = _scan_cache(body, x, params["blocks"], cache["blocks"],
                                 cfg, run)
+    if bt is None:
+        rows = {f"pos{i}": new_blocks[f"pos{i}"]
+                for i, spec in enumerate(pattern) if spec.mixer == "attn"}
+        new_blocks = {**new_blocks, **write_decode_rows(
+            cache["blocks"], rows, pos, cfg, run, rules, long_ctx=long_ctx)}
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     head = params["lm_head"] if "lm_head" in params else params["embed"].T
     logits = L.lm_logits({"lm_head": head}, x, rules)
@@ -504,7 +531,9 @@ def decode_step(params, cache, tokens, cfg: ArchConfig, run: RunConfig,
 
 def decode_step_encdec(params, cache, tokens, cfg: ArchConfig, run: RunConfig,
                        rules: ShardingRules | None):
-    """Whisper decode: self-attention cache + precomputed cross K/V."""
+    """Whisper decode: self-attention cache + precomputed cross K/V. The
+    scan returns each layer's new self-attention K/V rows, written into the
+    cache after it (``write_decode_rows``)."""
     pos = cache["pos"]
     x = L.embed_tokens(params, tokens, rules, run)
     pattern = cfg.layer_pattern()
@@ -545,6 +574,8 @@ def decode_step_encdec(params, cache, tokens, cfg: ArchConfig, run: RunConfig,
     else:
         (x, _), new_blocks = lax.scan(body, (x, 0),
                                       (params["blocks"], cache["blocks"]))
+    new_blocks = write_decode_rows(cache["blocks"], new_blocks, pos, cfg, run,
+                                   rules)
     x = L.rms_norm(params["final_norm"], x, cfg.norm_eps)
     head = params["lm_head"] if "lm_head" in params else params["embed"].T
     logits = L.lm_logits({"lm_head": head}, x, rules)

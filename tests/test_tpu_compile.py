@@ -1,6 +1,7 @@
 """Compile the fused PK GEMM x collective kernels for a described TPU v5e
 2x2 (no chip needed), at tinyllama-1.1b's tensor-parallel MLP and
-attention-out widths over a 4-device mesh.
+attention-out widths over a 4-device mesh; and the slab decode step for one
+described chip, to show that it updates its donated cache in place.
 
 Interpret mode accepts kernels the chip's compiler refuses (row slices off
 the dtype's tiling, HBM scratch, too much VMEM). These tests run Mosaic
@@ -12,19 +13,25 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro import compat
 from repro.configs import get_config
+from repro.configs.base import RunConfig
 from repro.core import costmodel as cm
 from repro.core.schedule import choose_gemm_chunks
 from repro.kernels import collective_matmul as cmm
+from repro.models import transformer as T
+from repro.train.step import make_serve_step
 
 N_DEV = 4
 CFG = get_config("tinyllama-1.1b")
@@ -36,7 +43,7 @@ KIND = {"all_gather_matmul": "all_gather",
 
 
 @pytest.fixture(scope="module")
-def mesh():
+def topo():
     from jax.experimental import topologies
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     topo = topologies.get_topology_desc(platform="tpu",
@@ -47,8 +54,13 @@ def mesh():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield Mesh(np.array(topo.devices[:N_DEV]), ("x",))
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return Mesh(np.array(topo.devices[:N_DEV]), ("x",))
 
 
 def _coords(op: str, m: int, k_glob: int, n_glob: int):
@@ -145,3 +157,41 @@ def test_unaligned_row_block_is_refused_by_the_compiler(mesh):
     block at decode batch 8 (interpret mode would have run it)."""
     with pytest.raises(Exception, match="aligned to tiling"):
         _compile(mesh, "matmul_all_reduce", 8, F, D)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_decode_updates_the_cache_in_place(topo, kv_dtype):
+    """The slab decode step, its cache donated as the serving engine
+    donates it, compiles for one chip to a program that writes each cache
+    leaf where it lies: every leaf is aliased input to output, and the
+    temporaries stay below one layer's K slab. Threading the slabs through
+    the layer scan as its outputs made XLA copy every stacked slab once a
+    step. Four layers, 32 slots of 16384 positions: slabs too large for the
+    chip's fast memory, where such copies would not count as temporaries."""
+    cfg = dataclasses.replace(get_config("tinyllama-1.1b").reduced(),
+                              n_layers=4)
+    run = RunConfig(dp_axes=("data",), fsdp=False)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def abstract(tmpl):
+        return jax.tree.map(
+            lambda pd: jax.ShapeDtypeStruct(pd.shape, pd.dtype,
+                                            sharding=one_chip),
+            tmpl, is_leaf=lambda x: isinstance(x, T.PD))
+
+    b = 32
+    params = abstract(T.param_template(cfg, run, None))
+    cache = abstract(T.cache_template(cfg, run, None, batch=b, s_max=16384,
+                                      slot_pos=True, kv_dtype=kv_dtype))
+    tokens = jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_serve_step(cfg, run, None),
+                       donate_argnums=(1,)).lower(params, cache,
+                                                  tokens).compile()
+    first = len(jax.tree.leaves(params))       # the cache's first parameter
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        compiled.as_text())}
+    assert set(range(first, first + len(jax.tree.leaves(cache)))) <= aliased
+    k = cache["blocks"]["pos0"]["k"]
+    layer_k = k.size // k.shape[0] * k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_k
