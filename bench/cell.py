@@ -1,12 +1,13 @@
 """A cell's set-up: its entry in ``BENCHMARK.json``, its configuration and
-traffic files, the chips, the weights drawn from the seed, and the serving
-engine under test, built from the program's public pieces the way
-``launch/serve.build_engine`` builds one (which takes no depth-cut
-configuration)."""
+traffic files, the model module its configuration names, the chips, the
+weights drawn from the seed, and the serving engine under test, built from
+the program's public pieces the way ``launch/serve.build_engine`` builds
+one (which takes no depth-cut configuration)."""
 
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import sys
 from pathlib import Path
@@ -16,23 +17,38 @@ import numpy as np
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 
-#: published config.json key -> the program's ArchConfig field
-HF_KEYS = {"num_hidden_layers": "n_layers", "hidden_size": "d_model",
-           "num_attention_heads": "n_heads",
-           "num_key_value_heads": "n_kv_heads", "head_dim": "hd",
-           "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-           "rope_theta": "rope_theta"}
-ACTS = {"gelu_pytorch_tanh": "gelu", "silu": "silu"}
-
 
 def load_json(path) -> dict:
     return json.loads(Path(path).read_text())
 
 
+def load_file(path: Path):
+    """The Python file at ``path`` as a module, executed once per path."""
+    key = f"bench:{Path(path).resolve()}"
+    mod = sys.modules.get(key)
+    if mod is None:
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[key]
+            raise
+    return mod
+
+
+def load_model(name: str, root: Path = ROOT):
+    """The model module ``bench/models/<name>.py`` that a configuration
+    file names under ``bench_model``."""
+    return load_file(root / "bench" / "models" / f"{name}.py")
+
+
 def resolve(workload: str, root: Path = ROOT) -> dict:
-    """The cell named ``workload``: its chips, configuration and traffic,
-    and the metrics it reports (end-to-end with ``--trace 0``, per-layer
-    with ``--trace 1``), all found by the names ``BENCHMARK.json`` gives."""
+    """The cell named ``workload``: its chips, configuration, model module
+    and traffic, and the metrics it reports (end-to-end with ``--trace 0``,
+    per-layer with ``--trace 1``), all found by the names
+    ``BENCHMARK.json`` and the configuration file give."""
     spec = load_json(root / "BENCHMARK.json")
     cell = next((w for w in spec["workloads"] if w["name"] == workload),
                 None)
@@ -45,8 +61,9 @@ def resolve(workload: str, root: Path = ROOT) -> dict:
     per_layer = [m for m in spec["per_layer"]
                  if (workload in m["workloads"] if "workloads" in m
                      else m["moves"] in moves)]
-    return {"name": workload, "chips": cell["chips"],
-            "config": load_json(root / conf["file"]),
+    config = load_json(root / conf["file"])
+    return {"name": workload, "chips": cell["chips"], "config": config,
+            "model": load_model(config["bench_model"], root),
             "traffic": load_json(root / "bench" / "traffic"
                                  / f"{cell['traffic']}.json"),
             "end_to_end": e2e, "per_layer": per_layer,
@@ -62,22 +79,6 @@ def seed_words(seed: int, salt: int = 0) -> np.ndarray:
 
 def rng(seed: int, salt: int) -> np.random.Generator:
     return np.random.default_rng(seed_words(seed, salt))
-
-
-def arch_config(conf: dict):
-    """The program's ArchConfig for this configuration file, checked
-    against the file's published keys so the two cannot drift apart."""
-    from repro.configs import get_config
-
-    cfg = dataclasses.replace(get_config(conf["repro_arch"]),
-                              **conf["arch_overrides"])
-    want = {f: conf[k] for k, f in HF_KEYS.items() if k in conf}
-    want["act"] = ACTS[conf["hidden_act"]]
-    got = {f: getattr(cfg, f) for f in want}
-    if got != want:
-        raise ValueError(f"{conf['repro_arch']}: program config {got} "
-                         f"differs from the configuration file {want}")
-    return cfg
 
 
 def use_compile_cache() -> None:
@@ -190,15 +191,16 @@ class Built:
     engine: object       # ServingEngine
 
 
-def build(conf: dict, seed: int, devices=None) -> Built:
-    """Weights from the seed and the engine that serves them."""
+def build(model, conf: dict, seed: int, devices=None) -> Built:
+    """Weights from the seed and the engine that serves them, the program's
+    configuration checked by ``model.arch_config``."""
     from repro import compat
     from repro.configs.base import RunConfig, ServeConfig
     from repro.models import transformer as T
     from repro.models.sharding import ShardingRules
     from repro.runtime.serving import ServingEngine
 
-    cfg = arch_config(conf)
+    cfg = model.arch_config(conf)
     sv = dict(conf["serve"])
     sv["bucket_edges"] = tuple(sv["bucket_edges"])
     serve = ServeConfig(**sv)

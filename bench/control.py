@@ -37,7 +37,8 @@ def main() -> int:
     devices = C.check_devices(spec["chips"])
     C.use_compile_cache()
     conf, mix = spec["config"], spec["traffic"]
-    built = C.build(conf, args.seeds[0], devices)
+    model = spec["model"]
+    built = C.build(model, conf, args.seeds[0], devices)
     eng = built.engine
     vocab = built.cfg.vocab_size
     loop.warm_up(eng, vocab, C.rng(args.seeds[0], 4))
@@ -51,8 +52,8 @@ def main() -> int:
                          preroll_s=float(mix["preroll_s"]),
                          seconds=args.seconds)
         eng.take_undone()
-        got = reference.compare(eng.params, conf, run.reqs, C.rng(seed, 3),
-                                control=i < args.control)
+        got = reference.compare(model, eng.params, conf, run.reqs,
+                                C.rng(seed, 3), control=i < args.control)
         print(f"[control] seed {seed}: {got} "
               f"({time.perf_counter() - t:.1f} s)", flush=True)
     return 0

@@ -1,10 +1,11 @@
-"""Operations and bytes the served algorithm needs, from shapes alone.
+"""What the counts of every architecture share: the published peaks of the
+chip, and the width of a bf16 value.
 
-``shapes`` is the dict ``shapes_of`` builds from a configuration file's
-published keys. Counts are of useful work only: real prompt tokens, live
-decode slots and the KV each slot holds now, never bucket padding, inert
-group rows or the slab's unused tail. So a share of a peak computed from
-them cannot pass 100% unless the device time leaves out part of the work.
+The operations and bytes of a step belong to the architecture: each model
+module under ``bench/models/`` counts its own from shapes alone and from
+the ``bench/loop.Step`` it is given (the prompts a prefill admitted, the
+positions each live decode slot attends to). A count a module cannot give
+is ``None``, and a reader then reports nothing.
 """
 
 from __future__ import annotations
@@ -26,58 +27,12 @@ def peak(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def shapes_of(conf: dict) -> dict:
-    """Model shapes from a configuration file's published keys."""
-    d, hq = conf["hidden_size"], conf["num_attention_heads"]
-    return {"layers": conf["num_hidden_layers"], "d": d, "hq": hq,
-            "hkv": conf["num_key_value_heads"],
-            "hd": conf.get("head_dim", d // hq),
-            "ff": conf["intermediate_size"], "vocab": conf["vocab_size"],
-            "gated": conf["reference"]["mlp"] == "swiglu"}
-
-
-def layer_params(s: dict) -> int:
-    """Matrix parameters of one layer (norm vectors excluded)."""
-    attn = s["d"] * s["hd"] * (2 * s["hq"] + 2 * s["hkv"])
-    mlp = (3 if s["gated"] else 2) * s["d"] * s["ff"]
-    return attn + mlp
-
-
-def weight_bytes(s: dict) -> int:
-    """bf16 bytes of every parameter: layers, norms, embedding, head."""
-    per_layer = layer_params(s) + 2 * s["d"]
-    return BF16 * (s["layers"] * per_layer + 2 * s["vocab"] * s["d"]
-                   + s["d"])
-
-
-def kv_bytes_per_token(s: dict) -> int:
-    return BF16 * 2 * s["layers"] * s["hkv"] * s["hd"]
-
-
-def prefill_flops(s: dict, prompt_len: int) -> int:
-    """One prompt's prefill: every matrix of every layer on each of its
-    tokens, causal attention (QK^T and PV over the positions each token
-    sees), and the head on the last position only."""
-    n = prompt_len
-    dense = 2 * n * s["layers"] * layer_params(s)
-    attn = 4 * s["layers"] * s["hq"] * s["hd"] * n * (n + 1) // 2
-    return dense + attn + 2 * s["d"] * s["vocab"]
-
-
-def decode_flops(s: dict, kv_lens) -> int:
-    """One decode step over the live slots; ``kv_lens`` holds, per live
-    slot, the positions its new token attends to (cache plus itself)."""
-    rows = len(kv_lens)
-    dense = 2 * rows * (s["layers"] * layer_params(s) + s["d"] * s["vocab"])
-    attn = 4 * s["layers"] * s["hq"] * s["hd"] * sum(kv_lens)
-    return dense + attn
-
-
-def decode_bytes(s: dict, kv_lens) -> int:
-    """Bytes one decode step must read: every weight but the embedding
-    table (of which it gathers one row a slot), and the KV that each live
-    slot holds now."""
-    rows = len(kv_lens)
-    weights = weight_bytes(s) - BF16 * s["vocab"] * s["d"]
-    return (weights + BF16 * rows * s["d"]
-            + kv_bytes_per_token(s) * sum(kv_lens))
+def total(model, name: str, shapes: dict, steps) -> int | None:
+    """The model module's count ``name`` summed over ``steps``: ``None``
+    where the module has no such count or gives ``None`` for a step, never
+    another architecture's count in its place."""
+    count = getattr(model, name, None)
+    if count is None:
+        return None
+    per_step = [count(shapes, x) for x in steps]
+    return None if None in per_step else sum(per_step)

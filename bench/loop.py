@@ -16,6 +16,14 @@ Each step also records what the host did meanwhile (``host_counters``), so
 that a step far longer than its kind can be told apart: the main thread
 busy in Python, the machine's CPUs taken by the hypervisor, pages read from
 disk, or the thread waiting on the device.
+
+Engine counters: ``serve(counters=names)`` reads each named attribute of
+the engine before and after every step (``Step.counters``, the change
+over the step) and at the window's edges (``Run.counters``, the change
+over the window). An attribute the engine lacks reads ``None``. The names
+are those that the cell's readers and model module declare as
+``COUNTERS``, so a counter the program gains reaches a reader with no
+change here.
 """
 
 from __future__ import annotations
@@ -55,6 +63,7 @@ class Step:
     kv_lens: list            # decode: positions each live slot attends to
     prompt_lens: list        # prefill: prompts it admitted
     host: tuple = ()         # host_counters() over the step
+    counters: dict = dataclasses.field(default_factory=dict)  # engine's
 
 
 @dataclasses.dataclass
@@ -72,6 +81,8 @@ class Run:
     gc_s: float = 0.0        # the full collection before the pre-roll
     gc_objects: int = 0      # objects it walked
     gc_pauses: list = dataclasses.field(default_factory=list)  # after it
+    counters: dict = dataclasses.field(default_factory=dict)  # engine's
+    traced: tuple | None = None  # the bench.traced span, on this clock
 
     def due_in_window(self):
         return [r for r in self.reqs if self.w0 <= r.due < self.w1]
@@ -97,6 +108,16 @@ def host_counters() -> np.ndarray:
                      ru.ru_nivcsw])
 
 
+def read_counters(engine, names) -> dict:
+    return {n: getattr(engine, n, None) for n in names}
+
+
+def change(before: dict, after: dict) -> dict:
+    """Per counter, ``after - before``; ``None`` where either is."""
+    return {n: None if v is None or after[n] is None else after[n] - v
+            for n, v in before.items()}
+
+
 def warm_up(engine, vocab: int, seed_rng) -> None:
     """Compile every program the cell's window will run: each bucket's
     prefill, the decode step, and the cache scatter for each group size."""
@@ -116,7 +137,7 @@ def warm_up(engine, vocab: int, seed_rng) -> None:
 
 def serve(engine, traffic, *, preroll_s: float, seconds: float,
           compile_clock=None, trace_dir: str | None = None,
-          trace_s: float = 3.0) -> Run:
+          trace_s: float = 3.0, counters: tuple = ()) -> Run:
     """Serve the mix from its pre-roll's start to the window's end.
 
     Open loop: each request is submitted once the clock reaches its due
@@ -143,7 +164,7 @@ def serve(engine, traffic, *, preroll_s: float, seconds: float,
     gc.disable()
     try:
         run = _serve(engine, traffic, preroll_s, seconds, compile_clock,
-                     trace_dir, trace_s)
+                     trace_dir, trace_s, tuple(counters))
     finally:
         gc.enable()
         gc.callbacks.remove(on_gc)
@@ -152,7 +173,7 @@ def serve(engine, traffic, *, preroll_s: float, seconds: float,
 
 
 def _serve(engine, traffic, preroll_s, seconds, compile_clock, trace_dir,
-           trace_s) -> Run:
+           trace_s, counters) -> Run:
     import jax
 
     spec = traffic.spec
@@ -167,7 +188,9 @@ def _serve(engine, traffic, preroll_s, seconds, compile_clock, trace_dir,
     compiles0 = None
     trace_at = max(w0, w1 - trace_s - 1.0) if trace_dir else None
     traced = None
+    traced_span = None
     trace_start_s = 0.0
+    at_w0 = None                 # the counters at the window's start
 
     def submit(r: Req):
         with TraceAnnotation("bench.submit"):
@@ -201,15 +224,19 @@ def _serve(engine, traffic, preroll_s, seconds, compile_clock, trace_dir,
             break
         if compiles0 is None and t >= w0 and compile_clock is not None:
             compiles0 = compile_clock.programs
+        if at_w0 is None and t >= w0:
+            at_w0 = read_counters(engine, counters)
         if trace_at is not None and t >= trace_at and traced is None:
             t0 = clock()
             jax.profiler.start_trace(trace_dir)
             trace_start_s = clock() - t0
             traced = TraceAnnotation("bench.traced")
             traced.__enter__()
+            traced_span = (clock() - start, None)
         if traced is not None and trace_at is not None \
                 and clock() - start >= trace_at + trace_s:
             traced.__exit__(None, None, None)
+            traced_span = (traced_span[0], clock() - start)
             trace_at = None
         if spec["loop"] == "open":
             while nxt.due <= t:
@@ -223,12 +250,14 @@ def _serve(engine, traffic, preroll_s, seconds, compile_clock, trace_dir,
             continue
         live = [s.prompt_len + len(s.tokens) for s in engine.slots
                 if s is not None]
+        c0 = read_counters(engine, counters)
         h0 = host_counters()
         t0 = clock() - start
         with TraceAnnotation("bench.step"):
             kind = engine.step()
         t1 = clock() - start
         host = tuple((host_counters() - h0).tolist())
+        c1 = change(c0, read_counters(engine, counters))
         admitted = []
         for ev in engine.events[n_events:]:
             if ev[0] == "admit":
@@ -238,7 +267,7 @@ def _serve(engine, traffic, preroll_s, seconds, compile_clock, trace_dir,
                     admitted.append(len(r.prompt))
         n_events = len(engine.events)
         steps.append(Step(kind, t0, t1, live if kind == "decode" else [],
-                          admitted, host))
+                          admitted, host, c1))
         for s in engine.slots:
             if s is not None and s.rid in by_rid:
                 emit(by_rid[s.rid], len(s.tokens), t1)
@@ -256,12 +285,15 @@ def _serve(engine, traffic, preroll_s, seconds, compile_clock, trace_dir,
     if traced is not None:
         if trace_at is not None:
             traced.__exit__(None, None, None)
+            traced_span = (traced_span[0], clock() - start)
         jax.profiler.stop_trace()
+    window = change(at_w0 or dict.fromkeys(counters),
+                    read_counters(engine, counters))
     n_compiles = (compile_clock.programs - compiles0
                   if compile_clock is not None and compiles0 is not None
                   else 0)
     return Run(w0, w1, reqs, steps, gaps, tok_in, n_compiles, late, start,
-               trace_start_s)
+               trace_start_s, counters=window, traced=traced_span)
 
 
 def trace_file(trace_dir: str) -> str | None:

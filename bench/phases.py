@@ -185,7 +185,9 @@ def _in_module(mods, ops):
 
 def reduce(ev: dict, scopes: dict | None = None, top: int = 32) -> dict:
     """``bench.trace.reduce(ev)`` with its ``idle_gaps`` labelled by the
-    innermost harness or engine span after the clock shift, and:
+    innermost harness or engine span after the clock shift, its
+    ``device_ops`` named ``<program>/<op>`` (``none/<op>`` outside every
+    program), so that ops of one name in two programs stay apart, and:
     ``clock_lag_ms``; ``device_programs`` [[program, s]] (union of each
     program's module intervals); ``device_scopes`` [[program, scope, s]]
     (device self time of ops, ``unscoped`` where the program's HLO names
@@ -196,7 +198,7 @@ def reduce(ev: dict, scopes: dict | None = None, top: int = 32) -> dict:
     decode program). All per device, averaged over devices, in the traced
     window; a metric is None where its spans or programs are missing.
     ``scopes`` maps a program name to its ``scope_map``."""
-    base = TR.reduce(ev)
+    base = TR.reduce(ev, top=top)
     if not base:
         return {}
     scopes = scopes or {}
@@ -217,6 +219,7 @@ def reduce(ev: dict, scopes: dict | None = None, top: int = 32) -> dict:
     modules = ev.get("modules", {})
     prog_t: dict = defaultdict(float)
     scope_t: dict = defaultdict(float)
+    ops_t: dict = defaultdict(float)
     dec_dev, dec_attn, dec_planes = 0.0, 0.0, 0
     for plane, ops in planes.items():
         mods = [(n, max(s, w0), min(e, w1)) for n, s, e in
@@ -234,6 +237,7 @@ def reduce(ev: dict, scopes: dict | None = None, top: int = 32) -> dict:
         for (p, op), t in TR.leaf_times(_in_module(mods, ops)).items():
             sc = scopes.get(p, {}).get(op, UNSCOPED)
             scope_t[(p, sc)] += t / n_dev
+            ops_t[f"{p}/{op}"] += t / n_dev
             if p == DECODE and sc == DECODE_ISLAND:
                 attn += t
         if n_dec:
@@ -243,6 +247,8 @@ def reduce(ev: dict, scopes: dict | None = None, top: int = 32) -> dict:
     host = _decode_host(ev, lag, w0, w1)
     ms = 1e-6
     base.update(
+        device_ops=[[n, t * 1e-9] for n, t in
+                    sorted(ops_t.items(), key=lambda kv: -kv[1])[:top]],
         clock_lag_ms=lag * ms,
         device_programs=[[p, t * 1e-9] for p, t in
                          sorted(prog_t.items(), key=lambda kv: -kv[1])],
@@ -270,9 +276,8 @@ def _decode_host(ev: dict, lag: float, w0, w1):
     return idle / len(ev["devices"]) / len(steps) * 1e-6
 
 
-def pad_frac(before: tuple, after: tuple):
-    """``prefill_pad_frac`` between two snapshots of the engine's
-    (``prefill_tokens``, ``prefill_slot_tokens``): the share of the
-    dispatched prefill rows x bucket that held no prompt token."""
-    slots = after[1] - before[1]
-    return 1.0 - (after[0] - before[0]) / slots if slots else None
+def pad_frac(tokens: int, slot_tokens: int):
+    """``prefill_pad_frac`` from the change of the engine's
+    ``prefill_tokens`` and ``prefill_slot_tokens`` over a span: the share
+    of the dispatched prefill rows x bucket that held no prompt token."""
+    return 1.0 - tokens / slot_tokens if slot_tokens else None

@@ -31,12 +31,12 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 STALL_S = 0.5
+PAD = ("prefill_tokens", "prefill_slot_tokens")
 
 
 def keep_phases(engine) -> list:
     """Record, after every engine step, (end on the host clock, kind, its
-    phase split, the prefill token counters): the engine's ``step`` is
-    wrapped in place."""
+    phase split): the engine's ``step`` is wrapped in place."""
     steps = []
     step = engine.step
 
@@ -44,8 +44,7 @@ def keep_phases(engine) -> list:
         kind = step()
         if kind is not None:
             steps.append((time.perf_counter(), kind,
-                          dict(engine.last_phases), engine.prefill_tokens,
-                          engine.prefill_slot_tokens))
+                          dict(engine.last_phases)))
         return kind
 
     engine.step = recorded
@@ -55,26 +54,22 @@ def keep_phases(engine) -> list:
 def split(steps, run) -> dict:
     """Phase split of the window's steps: mean ms per phase of each kind,
     the longest step's split, the steps over ``STALL_S`` with the phase
-    that held most of each, and ``prefill_pad_frac`` from the counters at
-    the window's edges."""
+    that held most of each, and ``prefill_pad_frac`` from the engine's
+    counters over the window."""
     from bench.phases import pad_frac
 
     w0, w1 = run.start + run.w0, run.start + run.w1
     inside = [s for s in steps if w0 <= s[0] < w1]
     by_kind: dict = {}
-    for _, kind, ph, *_ in inside:
+    for _, kind, ph in inside:
         k = by_kind.setdefault(kind, {"steps": 0, "ms": {}})
         k["steps"] += 1
         for name, sec in ph.items():
             k["ms"][name] = k["ms"].get(name, 0.0) + sec * 1e3
     for k in by_kind.values():
         k["ms"] = {n: v / k["steps"] for n, v in sorted(k["ms"].items())}
-    total = [sum(ph.values()) for _, _, ph, *_ in inside]
+    total = [sum(ph.values()) for _, _, ph in inside]
     longest = max(range(len(inside)), key=total.__getitem__, default=None)
-    before = next(((s[3], s[4]) for s in reversed(steps) if s[0] < w0),
-                  (0, 0))
-    after = next(((s[3], s[4]) for s in reversed(steps) if s[0] < w1),
-                 before)
     return {
         "by_kind": by_kind,
         "longest": None if longest is None else {
@@ -82,9 +77,9 @@ def split(steps, run) -> dict:
             "phases_ms": {n: v * 1e3 for n, v in inside[longest][2].items()}},
         "stalls": [{"kind": kind, "ms": t * 1e3,
                     "held_by": max(ph, key=ph.get)}
-                   for (_, kind, ph, *_), t in zip(inside, total)
+                   for (_, kind, ph), t in zip(inside, total)
                    if t > STALL_S],
-        "prefill_pad_frac": pad_frac(before, after)}
+        "prefill_pad_frac": pad_frac(*(run.counters[n] for n in PAD))}
 
 
 def main(argv=None, *, cell=None, devices=None) -> int:
@@ -98,8 +93,7 @@ def main(argv=None, *, cell=None, devices=None) -> int:
 
     from bench import cell as C
     from bench import loop
-    from bench import phases as PH
-    from bench.run import end_to_end
+    from bench.run import end_to_end, reduce_trace
     from bench.traffic import Traffic
 
     spec = cell if cell is not None else C.resolve(args.workload)
@@ -107,7 +101,7 @@ def main(argv=None, *, cell=None, devices=None) -> int:
         devices = C.check_devices(spec["chips"])
         C.use_compile_cache()
     conf, mix = spec["config"], spec["traffic"]
-    built = C.build(conf, args.seed, devices)
+    built = C.build(spec["model"], conf, args.seed, devices)
     eng = built.engine
     vocab = built.cfg.vocab_size
     loop.warm_up(eng, vocab, C.rng(args.seed, 4))
@@ -116,7 +110,8 @@ def main(argv=None, *, cell=None, devices=None) -> int:
         else None
     run = loop.serve(eng, Traffic(mix, args.seed, vocab),
                      preroll_s=float(mix["preroll_s"]),
-                     seconds=args.seconds, trace_dir=trace_dir)
+                     seconds=args.seconds, trace_dir=trace_dir,
+                     counters=PAD)
     out = {"workload": spec["name"], "seed": args.seed,
            "device": {"platform": devices[0].platform,
                       "kind": devices[0].device_kind,
@@ -125,20 +120,9 @@ def main(argv=None, *, cell=None, devices=None) -> int:
            "phases": split(steps, run)}
     if trace_dir:
         path = loop.trace_file(trace_dir)
-        ev = PH.events(path) if path else None
+        if path:
+            out["trace"] = reduce_trace(path, eng, top=32)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        if ev is not None:
-            # the executables' fingerprints do not appear in the trace's
-            # module names, so scopes are matched by program name: one
-            # executable per name in a cell's window
-            t = time.perf_counter()
-            maps = {name: PH.scope_map(compiled.as_text())
-                    for name, compiled in eng.step_programs().items()}
-            out["hlo_s"] = time.perf_counter() - t
-            out["modules"] = sorted({m for mods in ev["modules"].values()
-                                     for m, _, _ in mods
-                                     if m.startswith("jit_serve_")})
-            out["trace"] = PH.reduce(ev, maps)
     text = json.dumps(out, indent=1)
     if args.out:
         Path(args.out).parent.mkdir(parents=True, exist_ok=True)

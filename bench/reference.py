@@ -1,28 +1,19 @@
-"""The plain reference: a float32 forward pass of the served decoder in
-straightforward ``jax.numpy`` at HIGHEST matmul precision, and the
-comparison that decides a run's ``correct``.
-
-It imports nothing of the program. It reads the weights by name from the
-tree the benchmark drew from the seed (``embed``, ``final_norm``,
-``lm_head``, and per layer ``blocks/pos0/{attn,mlp}/...`` stacked over
-layers), upcasts them one layer at a time, and follows the published layer
-equations with the departures each configuration file lists: pre-norm
-RMSNorm, rotate-half RoPE, grouped-query causal attention, a GELU (tanh) or
-SwiGLU MLP, no biases.
+"""The plain reference and the comparison that decides a run's ``correct``,
+for every architecture: each model module under ``bench/models/`` gives
+its own float32 forward pass (``logits``) in straightforward ``jax.numpy``
+at HIGHEST matmul precision, built from the pieces here, and imports
+nothing of the program.
 
 The comparison: run the reference once over each sampled request's prompt
 and served tokens, and take the widest gap by which a served token's
 logit lies below the reference's best at that position. The control
 (``fp8=True``) is the same pass with every projection's operands cast to
-float8 e4m3 (per-row activation and per-column weight scales), the step
-below the bfloat16 the configuration serves in; it reads the gap of the
-token that it puts first.
+float8 e4m3 (per-row activation and per-column weight scales, ``_mm``),
+the step below the bfloat16 the configuration serves in; it reads the gap
+of the token that it puts first.
 """
 
 from __future__ import annotations
-
-import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -31,27 +22,6 @@ import numpy as np
 HIGHEST = jax.lax.Precision.HIGHEST
 F32 = jnp.float32
 QBLOCK = 128             # query rows per attention block
-
-
-@dataclasses.dataclass(frozen=True)
-class Arch:
-    layers: int
-    hq: int
-    hkv: int
-    hd: int
-    vocab: int
-    theta: float
-    eps: float
-    mlp: str             # "gelu_tanh" | "swiglu"
-
-    @classmethod
-    def of(cls, conf: dict) -> "Arch":
-        d, hq = conf["hidden_size"], conf["num_attention_heads"]
-        eps = conf.get("rms_norm_eps", conf.get("norm_epsilon"))
-        return cls(conf["num_hidden_layers"], hq,
-                   conf["num_key_value_heads"], conf.get("head_dim", d // hq),
-                   conf["vocab_size"], float(conf["rope_theta"]), float(eps),
-                   conf["reference"]["mlp"])
 
 
 def _q8(x, axis):
@@ -81,52 +51,6 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("a", "fp8"))
-def _layer(x, blocks, i, a: Arch, fp8: bool):
-    """One decoder layer on x (T, d) in float32."""
-    at = {k: v[i].astype(F32) for k, v in blocks["attn"].items()}
-    ml = {k: v[i].astype(F32) for k, v in blocks["mlp"].items()}
-    t = x.shape[0]
-    h = _rms(x, at["norm"], a.eps)
-    q = _rope(_mm(h, at["wq"], fp8).reshape(t, a.hq, a.hd), a.theta)
-    k = _rope(_mm(h, at["wk"], fp8).reshape(t, a.hkv, a.hd), a.theta)
-    v = _mm(h, at["wv"], fp8).reshape(t, a.hkv, a.hd)
-    g = a.hq // a.hkv
-    k, v = jnp.repeat(k, g, axis=1), jnp.repeat(v, g, axis=1)
-    kpos = jnp.arange(t)
-
-    def block(args):
-        qb, q0 = args                                  # (QBLOCK, hq, hd)
-        s = jnp.einsum("qhd,khd->hqk", qb, k, precision=HIGHEST)
-        s = s * a.hd ** -0.5
-        qpos = q0 + jnp.arange(qb.shape[0])
-        s = jnp.where(kpos[None, None, :] <= qpos[None, :, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("hqk,khd->qhd", p, v, precision=HIGHEST)
-
-    nb = t // QBLOCK
-    o = jax.lax.map(block, (q.reshape(nb, QBLOCK, a.hq, a.hd),
-                            jnp.arange(nb) * QBLOCK))
-    x = x + _mm(o.reshape(t, a.hq * a.hd), at["wo"], fp8)
-    h = _rms(x, ml["norm"], a.eps)
-    if a.mlp == "swiglu":
-        u = jax.nn.silu(_mm(h, ml["w1"], fp8)) * _mm(h, ml["w3"], fp8)
-    else:
-        u = jax.nn.gelu(_mm(h, ml["w1"], fp8), approximate=True)
-    return x + _mm(u, ml["w2"], fp8)
-
-
-@jax.jit
-def _embed(embed, tokens):
-    return embed[tokens].astype(F32)
-
-
-@functools.partial(jax.jit, static_argnames=("a", "fp8"))
-def _head(x, norm, head, rows, a: Arch, fp8: bool):
-    h = _rms(x[rows], norm.astype(F32), a.eps)
-    return _mm(h, head.astype(F32), fp8)[:, :a.vocab]
-
-
 @jax.jit
 def _gap(ref, pick):
     """Per row: the reference's best logit less its logit of ``pick``."""
@@ -134,19 +58,8 @@ def _gap(ref, pick):
     return ref.max(axis=1) - got
 
 
-def logits(weights, a: Arch, tokens, rows, fp8: bool = False):
-    """Logits (len(rows), vocab) at ``rows`` of the sequence ``tokens``
-    (length a multiple of QBLOCK; padding after the real tokens does not
-    reach them under the causal mask)."""
-    blocks = weights["blocks"]["pos0"]
-    x = _embed(weights["embed"], tokens)
-    for i in range(a.layers):
-        x = _layer(x, blocks, np.int32(i), a, fp8)
-    return _head(x, weights["final_norm"], weights["lm_head"], rows, a, fp8)
-
-
-def gaps(weights, a: Arch, prompt, served, t_pad: int, n_pad: int,
-         control: bool = False) -> dict:
+def gaps(model, weights, conf: dict, prompt, served, t_pad: int,
+         n_pad: int, control: bool = False) -> dict:
     """Per served token, the reference's best logit less the logit of the
     token served (``program``) and, with ``control``, of the token the
     float8 pass puts first (``control``)."""
@@ -159,10 +72,10 @@ def gaps(weights, a: Arch, prompt, served, t_pad: int, n_pad: int,
     rows[:n] = p - 1 + np.arange(n)
     pick = np.zeros(n_pad, np.int32)
     pick[:n] = served
-    ref = logits(weights, a, tokens, rows)
+    ref = model.logits(weights, conf, tokens, rows)
     out = {"program": np.asarray(_gap(ref, pick))[:n]}
     if control:
-        low = logits(weights, a, tokens, rows, fp8=True)
+        low = model.logits(weights, conf, tokens, rows, fp8=True)
         out["control"] = np.asarray(_gap(ref, jnp.argmax(low, axis=1)))[:n]
     return out
 
@@ -183,19 +96,19 @@ def pad_to(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def compare(weights, conf: dict, reqs: list, r: np.random.Generator,
-            control: bool = False) -> dict:
+def compare(model, weights, conf: dict, reqs: list,
+            r: np.random.Generator, control: bool = False) -> dict:
     """The numbers ``correct`` is decided on, over a sample of the
-    finished requests: the widest logit gap and the tokens compared."""
+    finished requests, with the reference of ``model`` (the configuration's
+    module): the widest logit gap and the tokens compared."""
     serve = conf["serve"]
-    a = Arch.of(conf)
     t_pad = pad_to(serve["bucket_edges"][-1] + serve["max_new_tokens"],
                    QBLOCK)
     done = [q for q in reqs if q.tokens is not None]
     picked = sample(done, conf["check"]["requests"], r)
     prog, ctrl = [], []
     for q in picked:
-        g = gaps(weights, a, q.prompt, q.tokens, t_pad,
+        g = gaps(model, weights, conf, q.prompt, q.tokens, t_pad,
                  serve["max_new_tokens"], control)
         prog.append(g["program"])
         if control:

@@ -8,10 +8,14 @@ draw the weights from the seed on the device, build the serving engine,
 compile and warm every program the window runs, then serve the mix's
 pre-roll. The window then serves the mix for ``--seconds``; nothing
 compiles in it. Afterwards the device's peak memory is read, the engine's
-cache freed, and the float32 reference decides ``correct`` on a sample of
-the finished requests. The last line of standard output is one JSON
-object; ``--trace 1`` reports the per-layer metrics and profiles a few
-seconds of the window, ``--trace 0`` the end-to-end metrics.
+cache freed, and the float32 reference of the configuration's model module
+decides ``correct`` on a sample of the finished requests. The last line of
+standard output is one JSON object; ``--trace 0`` reports the end-to-end
+metrics. ``--trace 1`` reports the per-layer metrics: it profiles a few
+seconds of the window, keeps the engine counters that the readers and the
+model module declare (``COUNTERS``), and reduces the trace by program,
+scope and engine phase (``bench/phases.py``) with the scopes read from the
+step programs' HLO after the window.
 
 It exits 2, printing no result, when JAX finds no TPU, an unknown kind, or
 fewer chips than the cell needs.
@@ -23,7 +27,6 @@ T0 = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
@@ -35,15 +38,44 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
+BREAKDOWN = 10           # entries in each list of the traced breakdown
+
+
+def reader(metrics_dir: Path, name: str):
+    from bench.cell import load_file
+    return load_file(metrics_dir / f"{name}.py")
+
 
 def read_metric(metrics_dir: Path, name: str, rec: dict):
     """Value of per-layer metric ``name`` from its reader file, or None
     where the reader finds nothing to read."""
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", metrics_dir / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(rec)
+    return reader(metrics_dir, name).read(rec)
+
+
+def counters_of(spec: dict) -> tuple:
+    """The engine counters the cell's per-layer readers and its model
+    module declare (``COUNTERS``), each once."""
+    mods = [reader(spec["metrics_dir"], m["name"])
+            for m in spec["per_layer"]] + [spec["model"]]
+    names = [n for mod in mods for n in getattr(mod, "COUNTERS", ())]
+    return tuple(dict.fromkeys(names))
+
+
+def reduce_trace(path, eng, top: int = BREAKDOWN) -> dict:
+    """The trace reduced by program, scope and engine phase
+    (``bench/phases.reduce``), with ``hlo_s``, the seconds it took to read
+    the scopes from the HLO of the engine's step programs; read while the
+    engine still holds its cache. The executables' fingerprints do not
+    appear in the trace's module names, so scopes are matched by program
+    name: one executable per name in a cell's window."""
+    from bench import phases as PH
+
+    t = time.perf_counter()
+    scopes = {name: PH.scope_map(compiled.as_text())
+              for name, compiled in eng.step_programs().items()}
+    hlo_s = time.perf_counter() - t
+    out = PH.reduce(PH.events(path), scopes, top=top)
+    return {**out, "hlo_s": hlo_s} if out else {}
 
 
 def ttfts(run) -> list:
@@ -99,16 +131,30 @@ def stalls(run) -> str:
             f"{run.trace_start_s * 1e3:.3f} ms")
 
 
-def record(run, conf: dict, kind: str, chips: int, compile_s: float,
-           trace: dict, peak_bytes) -> dict:
-    """What the per-layer readers read: the run, the shapes and peaks the
-    counts need, set-up compile seconds, the trace reduction, memory."""
-    from bench.counts import peak, shapes_of
+def record(run, conf: dict, model, kind: str, chips: int,
+           compile_s: float, trace: dict, peak_bytes) -> dict:
+    """What the per-layer readers read: the run, the steps inside the
+    traced span, the model module with the shapes its counts take, the
+    peaks, set-up compile seconds, the trace reduction, memory."""
+    from bench.counts import peak
+    lo, hi = run.traced or (0.0, 0.0)
     # a CPU rehearsal has no peaks: the readers of shares report nothing
-    return {"run": run, "shapes": shapes_of(conf),
+    return {"run": run, "traced_steps": [s for s in run.steps
+                                         if lo <= s.t0 < hi],
+            "model": model, "shapes": model.shapes(conf),
             "peak": peak(kind) if kind != "cpu" else None,
             "chips": chips, "compile_s": compile_s, "trace": trace,
             "memory_peak_bytes": peak_bytes}
+
+
+def breakdown(trace: dict) -> dict:
+    """The traced run's breakdown: device ops by self time, named
+    ``<program>/<op>``; idle gaps by the span the host was in; device self
+    time by ``<program>/<scope>``."""
+    return {"device_ops": trace["device_ops"],
+            "idle_gaps": trace["idle_gaps"],
+            "device_scopes": [[f"{p}/{sc}", t] for p, sc, t
+                              in trace["device_scopes"][:BREAKDOWN]]}
 
 
 def main(argv=None, *, cell=None, devices=None) -> int:
@@ -130,7 +176,8 @@ def main(argv=None, *, cell=None, devices=None) -> int:
     clock = C.CompileClock()
     conf, mix = spec["config"], spec["traffic"]
 
-    built = C.build(conf, args.seed, devices)
+    model = spec["model"]
+    built = C.build(model, conf, args.seed, devices)
     eng = built.engine
     vocab = built.cfg.vocab_size
     loop.warm_up(eng, vocab, C.rng(args.seed, 4))
@@ -138,23 +185,24 @@ def main(argv=None, *, cell=None, devices=None) -> int:
     traffic = Traffic(mix, args.seed, vocab)
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if args.trace \
         else None
+    counters = counters_of(spec) if args.trace else ()
     preroll_s = float(mix["preroll_s"])
     run = loop.serve(eng, traffic, preroll_s=preroll_s, seconds=args.seconds,
-                     compile_clock=clock, trace_dir=trace_dir)
+                     compile_clock=clock, trace_dir=trace_dir,
+                     counters=counters)
     setup_s = run.start + run.w0 - T0      # process start to window start
     kind = devices[0].device_kind
     peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                      for d in devices)
     trace = {}
     if trace_dir:
-        from bench import trace as TR
         path = loop.trace_file(trace_dir)
-        trace = TR.reduce(TR.events(path)) if path else {}
+        trace = reduce_trace(path, eng) if path else {}
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     eng.cache = None                 # free the slot slab for the reference
     gc.collect()
-    check = reference.compare(eng.params, conf, run.reqs,
+    check = reference.compare(model, eng.params, conf, run.reqs,
                               C.rng(args.seed, 3))
     served = [r for r in run.reqs if r.tokens is not None]
     short = sum(len(r.tokens) != r.out_len for r in served)
@@ -173,7 +221,7 @@ def main(argv=None, *, cell=None, devices=None) -> int:
     out = {"correct": correct, "attempted": len(attempted),
            "failed": failed}
     if args.trace:
-        rec = record(run, conf, kind, len(devices), compile_s, trace,
+        rec = record(run, conf, model, kind, len(devices), compile_s, trace,
                      peak_bytes)
         metrics = {}
         for m in spec["per_layer"]:
@@ -184,8 +232,7 @@ def main(argv=None, *, cell=None, devices=None) -> int:
         device["window_s"] = trace.get("window_s", 0.0)
         out.update(metrics=metrics, device=device)
         if trace:
-            out["breakdown"] = {"device_ops": trace["device_ops"],
-                                "idle_gaps": trace["idle_gaps"]}
+            out["breakdown"] = breakdown(trace)
     else:
         e2e = end_to_end(run, setup_s)
         out.update(metrics={m["name"]: {"value": e2e[m["name"]],
@@ -200,7 +247,9 @@ def main(argv=None, *, cell=None, devices=None) -> int:
           f"hits), {run.compiles_in_window} programs compiled or loaded in "
           f"the window; generator late by up to "
           f"{run.late_max_s * 1e3:.3f} ms; "
-          f"{check['requests']} requests compared", file=sys.stderr)
+          f"{check['requests']} requests compared"
+          + (f"; scopes read from the HLO in {trace['hlo_s']:.3f} s"
+             if trace else ""), file=sys.stderr)
     print(f"[bench] {tails(run)}", file=sys.stderr)
     print(f"[bench] {stalls(run)}", file=sys.stderr)
     for name, c in checks.items():

@@ -36,7 +36,7 @@ def main() -> int:
     spec = C.resolve(args.workload)
     devices = C.check_devices(spec["chips"])
     C.use_compile_cache()
-    built = C.build(spec["config"], args.seed, devices)
+    built = C.build(spec["model"], spec["config"], args.seed, devices)
     eng = built.engine
     vocab = built.cfg.vocab_size
     loop.warm_up(eng, vocab, C.rng(args.seed, 4))

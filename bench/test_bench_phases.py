@@ -1,8 +1,9 @@
 """The phase, program and scope reduction (``bench/phases.py``): on the
 recorded v5e trace and ``test_bench_trace``'s hand-made events it leaves
-every key of ``bench/trace.reduce`` as that gives it; on hand-made events
-with engine spans and a device clock a millisecond behind, it finds the
-lag, labels idle gaps by engine phase and reads the per-layer values."""
+every key of ``bench/trace.reduce`` as that gives it, but for the device
+ops, which it names by program; on hand-made events with engine spans and
+a device clock a millisecond behind, it finds the lag, labels idle gaps by
+engine phase and reads the per-layer values."""
 
 import json
 
@@ -17,9 +18,17 @@ LAG = 1.0          # the device clock reads this many ms behind the host's
 
 
 def _same_as_trace(ev):
+    """Every key as ``bench/trace.reduce`` gives it; the device ops with the
+    same self times, each name prefixed by its program."""
     base = TR.reduce(ev)
     got = PH.reduce(ev)
-    assert json.dumps({k: got[k] for k in base}) == json.dumps(base)
+    same = [k for k in base if k != "device_ops"]
+    assert json.dumps({k: got[k] for k in same}) == \
+        json.dumps({k: base[k] for k in same})
+    assert [t for _, t in got["device_ops"]] == \
+        pytest.approx([t for _, t in base["device_ops"]])
+    assert [n.split("/", 1)[1] for n, _ in got["device_ops"]] == \
+        [n for n, _ in base["device_ops"]]
     return got
 
 
@@ -29,6 +38,8 @@ def test_recorded_trace_keys_unchanged():
     assert {PH.program(m) for m, _, _ in ev["modules"]["/device:TPU:0"]} \
         == {"jit__lambda"}
     got = _same_as_trace(ev)
+    assert [n for n, _ in got["device_ops"]][:2] == \
+        ["jit__lambda/convolution_tanh_fusion", "jit__lambda/fusion"]
     assert got["clock_lag_ms"] == 0.0
     assert got["device_programs"][0][0] == "jit__lambda"
     assert got["decode_device_ms"] is None
@@ -38,6 +49,8 @@ def test_recorded_trace_keys_unchanged():
 
 def test_hand_made_trace_keys_unchanged():
     got = _same_as_trace(hand_made())
+    assert {n for n, _ in got["device_ops"]} == \
+        {"none/fusion.1", "none/all-reduce.2", "none/fusion.3"}
     assert got["clock_lag_ms"] == 0.0
     assert got["device_programs"] == []
     assert got["device_scopes"][0][:2] == ["none", "unscoped"]
@@ -115,6 +128,11 @@ def test_programs_scopes_and_decode_metrics():
     assert scopes[("jit_serve_decode", "mlp")] == pytest.approx(23.8)
     assert scopes[("jit_serve_prefill_3072", "mlp")] == pytest.approx(17.79)
     assert scopes[("jit_scatter", "unscoped")] == pytest.approx(1.8)
+    # one op name in two programs: two device-op rows
+    ops = {n: t * 1e3 for n, t in got["device_ops"]}
+    assert ops["jit_serve_decode/fusion.1"] == pytest.approx(10.49)
+    assert ops["jit_serve_prefill_3072/fusion.1"] == pytest.approx(17.79)
+    assert "fusion.1" not in ops
     assert got["decode_device_ms"] == pytest.approx(34.29)
     # the decode step span 10.05-45.95 on the host clock holds device work
     # 10.05-10.09 and 10.50-44.79 after the 0.99 ms shift
@@ -137,8 +155,8 @@ def test_metrics_are_none_without_spans_or_scopes():
 
 
 def test_pad_frac():
-    assert PH.pad_frac((100, 400), (400, 1200)) == pytest.approx(1 - 300 / 800)
-    assert PH.pad_frac((100, 400), (100, 400)) is None
+    assert PH.pad_frac(300, 800) == pytest.approx(1 - 300 / 800)
+    assert PH.pad_frac(0, 0) is None
 
 
 HLO = """HloModule jit_serve_decode, is_scheduled=true
